@@ -71,9 +71,12 @@ per-mode us/step, synaptic events/s, engine and backend, plus
 fused-vs-unfused speedups.
 
 On CPU the Pallas engines run in interpret mode, so the fused-vs-unfused
-numbers are an emulation proxy; the kernels compile natively on TPU where
-the HBM round-trips the fusion removes actually dominate (run there for
-the real comparison)."""
+numbers are an emulation proxy.  On a TPU the Pallas synapse kernels do
+not compile (``kernels.dispatch.PALLAS_GATHER_LIMIT``), and the
+``dist``/``plastic``/``overlap``/``ingest`` modes start child processes
+that would need the chip this process holds: there ``--mode`` accepts
+only ``ref``, ``ckpt``, ``serialization`` and ``recovery``, which run
+in-process, and refuses the rest with a message."""
 from __future__ import annotations
 
 import argparse
@@ -92,6 +95,10 @@ import numpy as np
 from repro.snn import Session, SimConfig, microcircuit, to_dcsr
 
 DEFAULT_JSON = "BENCH_spike_throughput.json"
+# refused on a TPU: they compare compiled Pallas engines, or start child
+# processes (fake-device distributed runs, RSS probes) that need the chip
+OFF_TPU_MODES = ("fused", "dist", "plastic", "overlap", "event", "ingest",
+                 "all")
 
 
 def _time_session(ses, steps, n, m):
@@ -841,6 +848,13 @@ def main(argv=None, quick=None):
                     help="perf-report path (merged across invocations)")
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args(argv)
+    if args.mode in OFF_TPU_MODES and jax.default_backend() == "tpu":
+        ap.error(
+            f"--mode {args.mode} does not run on a TPU: it compares the "
+            "Pallas engines, which the TPU compiler refuses, or starts "
+            "child processes that would need the chip this process holds; "
+            "use --mode ref, ckpt, serialization or recovery"
+        )
     # fused and dist share one workload so the k=1 vs distributed columns
     # of the JSON grid measure the same net
     pallas_scale = args.scale if args.scale is not None else (

@@ -57,6 +57,18 @@ COLLECTIVES = (
 # pinned to f32 state / s32 indices, so any 8-byte (or complex) result
 # in the compiled program is an accidental promotion
 WIDE_DTYPES = ("f64", "s64", "u64", "c128")
+# ...except the threefry RNG's own counter: jax lowers the (seed, t) noise
+# draw's counter as a u64 iota (op_name ``.../iota_2x32_shape``) shifted
+# right by a scalar u64 literal and split into two u32 words; that u64
+# never leaves the RNG.  A scalar literal carries no data, so it is exempt
+# wherever XLA hoists it.
+RNG_COUNTER_OP = '/iota_2x32_shape"'
+
+
+def _rng_counter_u64(line: str, shape: str, op: str) -> bool:
+    return RNG_COUNTER_OP in line or (
+        op == "constant" and shape.startswith("u64[]")
+    )
 
 # ops that move no HBM bytes / are bookkeeping
 _FREE_OPS = {
@@ -409,10 +421,9 @@ def wide_dtype_ops(
     hlo_text: str, forbidden: Tuple[str, ...] = WIDE_DTYPES
 ) -> List[Tuple[str, str, str]]:
     """Every instruction whose *result* carries a forbidden (8-byte)
-    dtype: ``(computation, instruction name, dtype)``.  ``constant`` /
-    ``parameter`` / ``iota`` feeding nothing wide would be flagged at the
-    consumer anyway, so no ops are exempted — an empty return is the
-    contract."""
+    dtype: ``(computation, instruction name, dtype)``.  The one exemption
+    is the RNG's u64 counter (``RNG_COUNTER_OP``); any other wide
+    result is reported — an empty return is the contract."""
     out: List[Tuple[str, str, str]] = []
     for comp, lines in _split_computations(hlo_text).items():
         for line in lines:
@@ -420,7 +431,9 @@ def wide_dtype_ops(
             if m is None:
                 continue
             for dt, _ in _parse_shape(m[1]):
-                if dt in forbidden:
+                if dt in forbidden and not (
+                    dt == "u64" and _rng_counter_u64(line, m[1], m[2])
+                ):
                     out.append((comp, m[0], dt))
     return out
 

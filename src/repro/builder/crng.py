@@ -90,15 +90,18 @@ def word_matrix(seed, stream, rows, j0, n_words, xp=np):
     holds word ``j0 + j`` of the stream keyed by ``(seed, stream)`` at
     counter ``row``.  Word ``w`` is output half ``w % 2`` of the cipher
     applied at counter ``(row, w // 2)`` — so the matrix is independent
-    of how rows and words are chunked across calls.
+    of how rows and words are chunked across calls.  Each counter's
+    cipher runs once, both halves interleaved, then the ``n_words``
+    window is sliced out.
     """
     u32 = xp.uint32
     rows = xp.asarray(rows, u32).reshape(-1, 1)
-    j = xp.asarray(j0, u32) + xp.arange(n_words, dtype=u32).reshape(1, -1)
-    pair = j >> u32(1)
-    parity = j & u32(1)
+    p0 = int(j0) >> 1
+    n_pairs = ((int(j0) + int(n_words) + 1) >> 1) - p0
+    pair = xp.asarray(p0, u32) + xp.arange(n_pairs, dtype=u32).reshape(1, -1)
     x0, x1 = threefry2x32(seed, stream, rows, pair, xp=xp)
-    return xp.where(parity == 0, x0, x1)
+    words = xp.stack([x0, x1], axis=-1).reshape(rows.shape[0], 2 * n_pairs)
+    return words[:, int(j0) & 1 : (int(j0) & 1) + int(n_words)]
 
 
 def mulhi32(a, b, xp=np):

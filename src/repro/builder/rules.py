@@ -237,7 +237,7 @@ def microcircuit_rules(scale: float = 1.0, seed: int = 0, g: float = 4.0,
             p = float(PD14_PROBS[ti][si])
             if p <= 0.0:
                 continue
-            inh = src.endswith("i")
+            inh = src.endswith("I")
             rules.append(
                 ConnectRule(
                     src=src, dst=tgt, p=p, no_self=(src == tgt),

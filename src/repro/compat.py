@@ -1,30 +1,28 @@
-"""Version compatibility shims for the jax API surface this repo uses.
+"""Thin helpers over the jax API surface this repo uses (jax 0.9).
 
-The codebase is written against the modern jax API (``jax.shard_map`` with
-``check_vma=``); older releases (such as the 0.4.x line pinned in this
-container) only expose ``jax.experimental.shard_map.shard_map`` with the
-pre-rename ``check_rep=`` keyword.  Everything in-repo imports ``shard_map``
-from here so both API generations work unmodified.
+Everything in-repo imports ``shard_map`` from here, so a future API move
+has one place to land.
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable
 
 import jax
+from jax.sharding import AbstractMesh
 
 __all__ = ["shard_map", "abstract_mesh", "cost_analysis", "pmean"]
+
+shard_map = jax.shard_map
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def pmean(x, axis_name):
     """``jax.lax.pmean`` with an explicit VJP (pmean is its own transpose).
 
-    On the jax 0.4.x line, transposing a pmean/psum inside ``shard_map``
-    fails when the cotangent is a symbolic ``Zero`` (unused aux outputs of
-    a differentiated shard_map produce exactly that).  ``custom_vjp``
-    materializes cotangents before ``bwd`` runs, sidestepping the bug while
-    keeping the exact gradient.
+    Transposing a pmean inside ``shard_map`` can receive a symbolic
+    ``Zero`` cotangent (unused aux outputs of a differentiated shard_map
+    produce exactly that); ``custom_vjp`` materializes cotangents before
+    ``bwd`` runs while keeping the exact gradient.
     """
     return jax.lax.pmean(x, axis_name)
 
@@ -41,44 +39,10 @@ pmean.defvjp(_pmean_fwd, _pmean_bwd)
 
 
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict: modern jax returns a
-    dict, the 0.4.x line a one-element list of dicts (one per program)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``Compiled.cost_analysis()``, empty dict when XLA reports none."""
+    return compiled.cost_analysis() or {}
 
 
-def abstract_mesh(axis_sizes, axis_names):
-    """Construct a ``jax.sharding.AbstractMesh`` across API generations.
-
-    Modern jax takes ``AbstractMesh(axis_sizes, axis_names)``; the 0.4.x
-    line takes a single ``((name, size), ...)`` shape tuple.
-    """
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-
-
-def _wrap_legacy(sm: Callable) -> Callable:
-    """Adapt the jax<=0.4 experimental entry point: accept the modern
-    ``check_vma=`` keyword and forward it as ``check_rep=``."""
-
-    @functools.wraps(sm)
-    def shard_map(f: Callable, *args: Any, **kwargs: Any) -> Callable:
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return sm(f, *args, **kwargs)
-
-    return shard_map
-
-
-if hasattr(jax, "shard_map"):  # jax >= 0.6: public, already takes check_vma
-    shard_map = jax.shard_map
-else:  # jax 0.4.x/0.5.x: experimental module, check_rep keyword
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    shard_map = _wrap_legacy(_experimental_shard_map)
+def abstract_mesh(axis_sizes, axis_names) -> AbstractMesh:
+    """A device-free ``AbstractMesh`` of the given axis sizes and names."""
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))

@@ -40,7 +40,8 @@ class ELLBucket:
     cols: Array  # (R, K) int32 global source ids (0 where invalid)
     weights: Array  # (R, K) float32 (0 where invalid)
     valid: Array  # (R, K) bool
-    edge_index: Array  # (R, K) int64 -> partition CSR edge position, -1 pad
+    edge_index: Array  # (R, K) -> partition CSR edge position, -1 pad;
+    # int32 while the partition holds < 2**31 edges, else int64
     row_map: Array  # (R,) int32 virtual row -> actual local row
     identity_rows: bool  # row_map[i] == i for i < n_rows
 
@@ -99,18 +100,22 @@ def build_delay_ell(
     use small values to keep oracles readable.
     """
     n_p = part.n
-    delays = part.edge_state[:, EDGE_DELAY].astype(np.int64)
+    # edge positions and rows in the narrowest integer that holds them:
+    # at full scale (~0.3B edges) int64 index arrays cost gigabytes
+    idx_t = np.int32 if part.m < 2**31 else np.int64
+    delays = part.edge_state[:, EDGE_DELAY].astype(np.int32)
     delays = np.maximum(delays, min_delay)
     rows_of_edge = np.repeat(
-        np.arange(n_p, dtype=np.int64), part.in_degree()
+        np.arange(n_p, dtype=idx_t), part.in_degree()
     )
     buckets: List[ELLBucket] = []
     for d in np.unique(delays) if part.m else []:
-        sel = np.flatnonzero(delays == d)  # sorted by (row, col) already
+        # sorted by (row, col) already
+        sel = np.flatnonzero(delays == d).astype(idx_t)
         r = rows_of_edge[sel]
         counts = np.bincount(r, minlength=n_p)
-        starts = np.cumsum(counts) - counts
-        pos = np.arange(len(sel)) - starts[r]
+        starts = (np.cumsum(counts) - counts).astype(idx_t)
+        pos = np.arange(len(sel), dtype=idx_t) - starts[r]
 
         if max_k is not None and counts.max() > max_k:
             # Split heavy rows into virtual rows of width <= max_k.
@@ -140,7 +145,7 @@ def build_delay_ell(
         cols = np.zeros((R, K), dtype=np.int32)
         weights = np.zeros((R, K), dtype=np.float32)
         valid = np.zeros((R, K), dtype=bool)
-        eidx = np.full((R, K), -1, dtype=np.int64)
+        eidx = np.full((R, K), -1, dtype=idx_t)
         cols[rr, pp] = part.col_idx[sel].astype(np.int32)
         weights[rr, pp] = part.edge_state[sel, EDGE_WEIGHT]
         valid[rr, pp] = True

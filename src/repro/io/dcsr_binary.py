@@ -217,7 +217,8 @@ def _write_part(path: str, item: Tuple[int, Dict[str, np.ndarray]]):
     buf = io.BytesIO()
     np.savez(buf, **arrs)
     try:
-        crc = write_bytes_verified(full, buf.getvalue(), "shard_write")
+        # getbuffer(): a view, not a second copy of a multi-GB shard
+        crc = write_bytes_verified(full, buf.getbuffer(), "shard_write")
     except OSError as e:
         raise ShardWriteError(part_id, full, e) from e
     return fn, crc

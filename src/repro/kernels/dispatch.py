@@ -13,7 +13,8 @@ Backends:
 Resolution order: explicit ``backend=`` argument > ``REPRO_BACKEND``
 environment variable > platform default (``pallas`` on TPU, otherwise
 ``pallas_interpret`` for direct kernel calls; the simulators default to
-``ref`` off-TPU, where XLA fusion of the oracles is already optimal).
+``ref`` on every platform — the synapse kernels' in-kernel gather does
+not compile for TPU, see ``PALLAS_GATHER_LIMIT``).
 
 Separately from the *kernel* backend, ``select_step_engine`` decides the
 *step engine*:
@@ -135,15 +136,52 @@ def resolve_backend(
     return default if default is not None else _platform_default()
 
 
+# What the TPU compiler refuses in every synapse kernel (spike_gather,
+# stdp_update, all fused_* and event_* kernels): each gathers activity by
+# presynaptic id from a VMEM-resident vector, ``jnp.take(act, cols)``.
+# Compiled for a TPU v5e with jax/jaxlib 0.9.0, Mosaic lowers only 2-D
+# gathers whose indices have the source's shape, so these kernels raise
+# ``NotImplementedError: Only 2D gather is supported``; they run only in
+# interpret mode.  The compile tests (tests/test_tpu_compile.py) pin this.
+PALLAS_GATHER_LIMIT = (
+    "the TPU compiler (Mosaic, jax 0.9) lowers no in-kernel 1-D gather: "
+    "jnp.take(activity, cols) from a VMEM-resident vector raises "
+    "'NotImplementedError: Only 2D gather is supported', and every "
+    "synapse kernel gathers that way"
+)
+
+# Why the simulators default to 'ref' on every platform (shown by
+# Session.describe()): the XLA-compiled step composes the jnp oracles, the
+# only synapse path the TPU compiler accepts today (PALLAS_GATHER_LIMIT);
+# on CPU it is also the fast path, XLA fusing the oracles.
+SIM_BACKEND_REASON = (
+    "simulators default to 'ref' (the XLA-compiled step) on every "
+    "platform: on TPU the Pallas synapse kernels do not compile ("
+    + PALLAS_GATHER_LIMIT + "); on CPU XLA's fusion of the oracles is "
+    "the fast path"
+)
+
+
 def resolve_sim_backend(backend: Optional[str] = None) -> str:
-    """Backend resolution for the simulators: same precedence chain, but
-    off-TPU they default to ``ref`` (XLA fusion of the oracles is the fast
-    CPU path), unlike direct kernel calls which default to interpret
-    mode."""
-    return resolve_backend(
-        backend,
-        default="pallas" if jax.default_backend() == "tpu" else "ref",
-    )
+    """Backend resolution for the simulators: explicit flag >
+    ``REPRO_BACKEND`` > ``ref`` on every platform, TPU included — see
+    ``SIM_BACKEND_REASON``.  Direct kernel calls keep the platform default
+    (``pallas`` on TPU, interpret mode elsewhere)."""
+    return resolve_backend(backend, default="ref")
+
+
+def require_compilable(backend: str, choice: "StepEngineChoice") -> None:
+    """Refuse, at engine construction, a compiled-Pallas step the TPU
+    compiler cannot build: every step engine propagates spikes through a
+    synapse kernel with an in-kernel gather (``PALLAS_GATHER_LIMIT``).
+    Raised, never swapped for another backend."""
+    if backend == "pallas":
+        raise ValueError(
+            f"backend='pallas' cannot run the {choice.engine!r} step "
+            f"engine: {PALLAS_GATHER_LIMIT}; use backend='ref' (the "
+            "default, XLA-compiled) or 'pallas_interpret' (CPU "
+            "validation of the kernels)"
+        )
 
 
 def lookup(op: str, backend: Optional[str] = None) -> Callable:

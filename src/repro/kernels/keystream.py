@@ -85,7 +85,7 @@ def keystream_pallas(
     return out[:n, :n_words]
 
 
-@functools.partial(jax.jit, static_argnames=("n_words",))
+@functools.partial(jax.jit, static_argnames=("j0", "n_words"))
 def keystream_jnp(seed, stream, rows, j0, n_words):
     """jnp oracle: the shared word_matrix evaluated under XLA."""
     return crng.word_matrix(seed, stream, rows, j0, n_words, xp=jnp)

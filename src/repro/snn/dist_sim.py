@@ -52,11 +52,13 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..compat import shard_map
+from ..launch.mesh import make_snn_mesh
 
 from ..core.dcsr import DCSRNetwork
 from ..core.ell import build_delay_ell
 from ..kernels.dispatch import (
-    event_id_cap, resolve_sim_backend, select_step_engine,
+    event_id_cap, require_compilable, resolve_sim_backend,
+    select_step_engine,
 )
 from ..kernels.event_step import (
     EventPlan, build_touch_masks, event_block_geometry,
@@ -79,7 +81,7 @@ class StackedNet:
     delays: Tuple[int, ...]
     cols: List[np.ndarray]  # per delay (k, R, K) int32
     weights: List[np.ndarray]
-    plastic: List[np.ndarray]
+    plastic: List[np.ndarray]  # empty when no partition has STDP synapses
     valid: List[np.ndarray]
     vtx_model: np.ndarray  # (k, n_p)
     vtx_state0: np.ndarray  # (k, n_p, S)
@@ -108,6 +110,7 @@ def stack_partitions(net: DCSRNetwork, cfg: SimConfig) -> StackedNet:
         [c.shape[0] for dv in devs for c in dv.cols]
         + [((n_p + cfg.align_rows - 1) // cfg.align_rows) * cfg.align_rows]
     )
+    any_plastic = any(d.any_plastic for d in devs)
     cols, weights, plastic, valid = [], [], [], []
     for d in delays:
         K = max(
@@ -119,15 +122,15 @@ def stack_partitions(net: DCSRNetwork, cfg: SimConfig) -> StackedNet:
         for dv in devs:
             if d in dv.delays:
                 i = dv.delays.index(d)
-                c, w, pl_, v = (np.asarray(dv.cols[i]),
-                                np.asarray(dv.weights0[i]),
-                                np.asarray(dv.plastic[i]),
-                                np.asarray(dv.valid[i]))
+                c, w, v = dv.cols[i], dv.weights0[i], dv.valid[i]
+                pl_ = (dv.plastic[i] if dv.plastic
+                       else np.zeros(c.shape, np.float32))
                 pr, pk = R - c.shape[0], K - c.shape[1]
                 pad = lambda a, pr=pr, pk=pk: np.pad(  # noqa: E731
                     a, ((0, pr), (0, pk))
                 )
-                c, w, pl_, v = pad(c), pad(w), pad(pl_), pad(v)
+                c, w, v = pad(c), pad(w), pad(v)
+                pl_ = pad(pl_) if any_plastic else None
             else:
                 c = np.zeros((R, K), np.int32)
                 w = np.zeros((R, K), np.float32)
@@ -139,14 +142,16 @@ def stack_partitions(net: DCSRNetwork, cfg: SimConfig) -> StackedNet:
             v_stack.append(v)
         cols.append(np.stack(c_stack))
         weights.append(np.stack(w_stack))
-        plastic.append(np.stack(p_stack))
+        if any_plastic:
+            plastic.append(np.stack(p_stack))
         valid.append(np.stack(v_stack))
     return StackedNet(
         n_p=n_p, k=net.k, delays=tuple(delays),
-        cols=cols, weights=weights, plastic=plastic, valid=valid,
-        vtx_model=np.stack([np.asarray(d.vtx_model) for d in devs]),
-        vtx_state0=np.stack([np.asarray(d.vtx_state0) for d in devs]),
-        any_plastic=any(d.any_plastic for d in devs),
+        cols=cols, weights=weights,
+        plastic=plastic, valid=valid,
+        vtx_model=np.stack([d.vtx_model for d in devs]),
+        vtx_state0=np.stack([d.vtx_state0 for d in devs]),
+        any_plastic=any_plastic,
         d_ring=max(max(delays, default=1), 1),
         identity_rows=all(
             b.identity_rows for e in ells for b in e.buckets
@@ -217,7 +222,8 @@ class DistSimulator:
                  cfg: Optional[SimConfig] = None,
                  mesh: Optional[Mesh] = None):
         cfg = SimConfig() if cfg is None else cfg
-        self._compiled: Dict[int, Tuple] = {}  # steps -> (jitted fn, args)
+        self._compiled: Dict[int, object] = {}  # steps -> jitted fn
+        self._device_args: Optional[Tuple] = None  # constants on the mesh
         self._sync_ells: Optional[List] = None  # per-part ELLs for sync
         self.net = net
         self.cfg = cfg
@@ -230,7 +236,7 @@ class DistSimulator:
             assert len(jax.devices()) >= k, (
                 f"need >= {k} devices for {k} partitions"
             )
-            mesh = jax.make_mesh((k,), ("parts",))
+            mesh = make_snn_mesh(k)
         self.mesh = mesh
         self.backend = resolve_sim_backend(cfg.backend)
         self.stdp_params = (
@@ -289,6 +295,7 @@ class DistSimulator:
             gather="dense" if cfg.gather == "auto" else cfg.gather,
             **sel_kw,
         )
+        require_compilable(self.backend, self.engine_choice)
         self.event_capable = _probe_event_capable(**sel_kw)
         # the non-plastic overlap engines gather build-time ownership
         # sub-panels; plastic panels stay whole (weights are state)
@@ -472,9 +479,14 @@ class DistSimulator:
         chunk length once instead of on every call."""
         if steps not in self._compiled:
             fn, args = self._build_run(steps)
-            self._compiled[steps] = (jax.jit(fn), args)
-        fn, args = self._compiled[steps]
-        return fn(*args, state)
+            if self._device_args is None:
+                # the constants go to their devices once, partition p to
+                # mesh device p, and are reused by every chunk program
+                self._device_args = jax.device_put(
+                    args, NamedSharding(self.mesh, P("parts"))
+                )
+            self._compiled[steps] = jax.jit(fn)
+        return self._compiled[steps](*self._device_args, state)
 
     def _build_run(self, steps: int):
         s = self.stacked
@@ -490,8 +502,8 @@ class DistSimulator:
 
         from .simulator import PartitionDeviceData
 
-        def local_run(vtx_model, noise_ids, cols, valid, plastic, touch,
-                      opan, carry):
+        def local_run(vtx_model, noise_ids, cols, plastic, touch, opan,
+                      carry):
             nd = len(s.delays)
             local_carry = dict(
                 t=carry["t"],
@@ -510,7 +522,7 @@ class DistSimulator:
                 cols=[c[0] for c in cols],
                 weights0=list(local_carry["weights"]),
                 plastic=[p_[0] for p_ in plastic],
-                valid=[v[0] for v in valid],
+                valid=[],
                 row_maps=[
                     jnp.arange(c.shape[1], dtype=jnp.int32) for c in cols
                 ],
@@ -568,8 +580,7 @@ class DistSimulator:
                 P("parts"),
                 P("parts"),
                 [P("parts")] * len(s.delays),
-                [P("parts")] * len(s.delays),
-                [P("parts")] * len(s.delays),
+                [P("parts")] * len(s.plastic),
                 [P("parts")] * (
                     len(self._event_touch)
                     if self._event_touch is not None else 0
@@ -583,8 +594,8 @@ class DistSimulator:
             out_specs=(out_carry_specs, out_specs),
             check_vma=False,
         )
-        # keep args as host numpy: run() lets jit transfer them; lower()
-        # maps them to ShapeDtypeStructs without any device allocation
+        # host numpy: run() places them on the mesh once; lower() maps
+        # them to ShapeDtypeStructs without any device allocation
         noise_ids = np.stack(
             [p.global_ids.astype(np.int32) for p in self.net.parts]
         )
@@ -592,8 +603,7 @@ class DistSimulator:
             [a for group in self._overlap_panels for a in group]
             if self._overlap_panels is not None else []
         )
-        args = (s.vtx_model, noise_ids, list(s.cols), list(s.valid),
-                list(s.plastic),
+        args = (s.vtx_model, noise_ids, list(s.cols), list(s.plastic),
                 list(self._event_touch)
                 if self._event_touch is not None else [],
                 opan)

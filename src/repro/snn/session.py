@@ -18,6 +18,12 @@ Engine selection (``engine="auto"``):
     trajectory — asserted in tests), so a partitioned network runs
     anywhere.
 
+The kernel backend defaults to ``ref`` — the XLA-compiled step — on every
+platform, TPU included: the Pallas synapse kernels do not compile for a TPU
+(``kernels.dispatch.PALLAS_GATHER_LIMIT``), and an explicit
+``SimConfig(backend="pallas")`` raises at construction.  ``describe()``
+names the engine and backend that run, and why.
+
 Both engines share one output contract (see :mod:`repro.snn.monitors`):
 ``spike_count`` ``(steps,)`` int32 summed over partitions, ``raster``
 ``(steps, n)`` uint8 in the global labelling, ``v_mean`` ``(steps,)``
@@ -111,7 +117,7 @@ from ..io.async_writer import AsyncWriter
 from ..io.dcsr_binary import (
     load_latest_valid, snapshot_network, snapshot_steps, write_snapshot,
 )
-from ..kernels.dispatch import EVENT_ACTIVITY_THRESHOLD
+from ..kernels.dispatch import EVENT_ACTIVITY_THRESHOLD, SIM_BACKEND_REASON
 from .dist_sim import DistSimulator
 from .reshard import RUNTIME_KEYS, concat_runtime, reshard_sim_state
 from .simulator import SimConfig, Simulator
@@ -483,11 +489,15 @@ class Session:
             gather=self._gather_mode,
             overlap=self.engine_choice.overlap,
         )
+        d["backend"] = self._current_engine.sim.backend
+        d["backend_reason"] = (
+            "set by SimConfig(backend=...) or REPRO_BACKEND"
+            if self.cfg.backend or os.environ.get("REPRO_BACKEND")
+            else SIM_BACKEND_REASON
+        )
         if isinstance(self._current_engine, _SingleEngine):
-            d["backend"] = self._current_engine.sim.backend
             d["ell_fill"] = self._current_engine.sim.ell.fill_factor
         else:
-            d["backend"] = self._current_engine.sim.backend
             d["exchange"] = self._current_engine.sim.exchange
         return d
 

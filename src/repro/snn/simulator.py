@@ -31,8 +31,8 @@ from ..core.ell import DelayELL, build_delay_ell
 from ..core.state import EDGE_WEIGHT
 from ..kernels import ops
 from ..kernels.dispatch import (
-    BACKENDS, StepEngineChoice, event_id_cap, resolve_sim_backend,
-    select_step_engine,
+    BACKENDS, StepEngineChoice, event_id_cap, require_compilable,
+    resolve_sim_backend, select_step_engine,
 )
 from ..kernels.event_step import EventPlan
 from .neurons import (
@@ -139,9 +139,24 @@ class SimConfig:
             )
 
 
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=[
+        "vtx_model", "vtx_state0", "cols", "weights0", "plastic", "valid",
+        "row_maps", "cols_local", "weights_local", "cols_remote",
+        "weights_remote",
+    ],
+    meta_fields=["n_p", "row_start", "delays", "identity_rows", "any_plastic"],
+)
 @dataclasses.dataclass
 class PartitionDeviceData:
-    """Device-resident constants + initial state for one partition."""
+    """Constants + initial state for one partition.
+
+    A pytree, so the engines hand it to their jitted programs as
+    *arguments*: closed-over arrays would be embedded in the program as
+    literals, and at full scale (gigabytes of synapse panels) compiling
+    such a program exhausts host memory.  ``plastic`` is empty for a
+    partition without STDP synapses (nothing reads it then)."""
 
     n_p: int
     row_start: int
@@ -171,29 +186,32 @@ def partition_device_data(
     net: DCSRNetwork,
     ell: DelayELL,
 ) -> PartitionDeviceData:
+    """The partition's constants as host arrays (callers place them)."""
     stdp_id = net.registry.edge_id("syn_stdp")
+    any_plastic = bool(np.any(part.edge_model == stdp_id))
     cols, w0, plastic, valid, rmaps, ident = [], [], [], [], [], []
     for b in ell.buckets:
-        cols.append(jnp.asarray(b.cols))
-        w0.append(jnp.asarray(b.weights))
-        is_stdp = np.zeros(b.cols.shape, dtype=np.float32)
-        sel = b.edge_index >= 0
-        is_stdp[sel] = (
-            part.edge_model[b.edge_index[sel]] == stdp_id
-        ).astype(np.float32)
-        plastic.append(jnp.asarray(is_stdp))
-        valid.append(jnp.asarray(b.valid.astype(np.float32)))
-        rmaps.append(jnp.asarray(b.row_map))
+        cols.append(b.cols)
+        w0.append(b.weights)
+        if any_plastic:
+            is_stdp = np.zeros(b.cols.shape, dtype=np.float32)
+            sel = b.edge_index >= 0
+            is_stdp[sel] = (
+                part.edge_model[b.edge_index[sel]] == stdp_id
+            ).astype(np.float32)
+            plastic.append(is_stdp)
+        valid.append(b.valid.astype(np.float32))
+        rmaps.append(b.row_map)
         ident.append(b.identity_rows)
     return PartitionDeviceData(
         n_p=part.n,
         row_start=part.row_start,
-        vtx_model=jnp.asarray(part.vtx_model),
-        vtx_state0=jnp.asarray(part.vtx_state),
+        vtx_model=part.vtx_model,
+        vtx_state0=part.vtx_state,
         delays=tuple(b.delay for b in ell.buckets),
         cols=cols, weights0=w0, plastic=plastic, valid=valid,
         row_maps=rmaps, identity_rows=tuple(ident),
-        any_plastic=bool(np.any(part.edge_model == stdp_id)),
+        any_plastic=any_plastic,
     )
 
 
@@ -729,48 +747,78 @@ class Simulator:
             max_k=cfg.max_k,
         )
         self.d_ring = max(self.ell.max_delay, 1)
-        self.dev = partition_device_data(part, net, self.ell)
+        host = partition_device_data(part, net, self.ell)
         self.backend = resolve_sim_backend(cfg.backend)
         stdp = (
             dict(net.registry.spec("syn_stdp").params)
-            if self.dev.any_plastic
+            if host.any_plastic
             else None
         )
-        self._step = make_core_step(
+        models = _models_present(net)
+        sel_kw = dict(
+            backend=self.backend,
+            models_present=models,
+            any_plastic=host.any_plastic and stdp is not None,
+            identity_exchange=True,
+            identity_rows=all(host.identity_rows),
+            n_delay_buckets=len(host.delays),
+            n_p=host.n_p,
+            n_global=net.n,
+            fused=cfg.fused,
+            event_cap_frac=cfg.event_cap_frac,
+        )
+        # k=1 is an identity exchange: overlap 'auto' resolves to 'off', an
+        # explicit mode is still validated by the selector (raises with
+        # fused=True — there is no collective to overlap)
+        self.engine_choice: StepEngineChoice = select_step_engine(
+            gather="dense" if cfg.gather == "auto" else cfg.gather,
+            overlap="off" if cfg.overlap == "auto" else cfg.overlap,
+            **sel_kw,
+        )
+        require_compilable(self.backend, self.engine_choice)
+        self.event_capable = _probe_event_capable(**sel_kw)
+        # the event schedule is built from the host panels; only what the
+        # step reads goes to the device (validity masks stay on the host)
+        self._event_plan = (
+            EventPlan.build(
+                host.cols, host.valid, net.n, self.d_ring,
+                event_id_cap(net.n, cfg.event_cap_frac),
+                interpret=self.backend != "pallas",
+            )
+            if self.engine_choice.event else None
+        )
+        self.dev = jax.device_put(dataclasses.replace(host, valid=[]))
+        self._noise_ids = jnp.asarray(part.global_ids, jnp.int32)
+        self._step_kw = dict(
             registry=net.registry,
-            models_present=_models_present(net),
+            models_present=models,
             dt=self.dt,
             noise_sigma=self.noise_sigma,
             base_key=jax.random.PRNGKey(cfg.seed),
             d_ring=self.d_ring,
             n_global=net.n,
-            dev=self.dev,
             backend=self.backend,
             stdp_params=stdp,
             exchange=lambda s, tr: (s, tr, jnp.zeros((), jnp.int32)),
-            noise_ids=jnp.asarray(part.global_ids, jnp.int32),
             record_raster=cfg.record_raster,
             record_v=cfg.record_v,
-            fused=cfg.fused,
-            gather=cfg.gather,
-            event_cap_frac=cfg.event_cap_frac,
-            # k=1 is an identity exchange: 'auto' resolves to 'off', an
-            # explicit mode is still validated by the selector (raises
-            # with fused=True — there is no collective to overlap)
-            overlap="off" if cfg.overlap == "auto" else cfg.overlap,
+            engine_choice=self.engine_choice,
         )
-        self.engine_choice: StepEngineChoice = self._step.engine_choice
-        self.event_capable = _probe_event_capable(
-            backend=self.backend,
-            models_present=_models_present(net),
-            any_plastic=self.dev.any_plastic and stdp is not None,
-            identity_exchange=True,
-            identity_rows=all(self.dev.identity_rows),
-            n_delay_buckets=len(self.dev.delays),
-            n_p=self.dev.n_p,
-            n_global=net.n,
-            fused=cfg.fused,
-            event_cap_frac=cfg.event_cap_frac,
+        # the step over the resident constants, for program analysis
+        # (repro.analysis.contracts traces it); run() rebuilds it over
+        # traced arguments instead
+        self._step = self._core_step(self.dev, self._noise_ids, self._touch())
+
+    def _touch(self):
+        return self._event_plan.touch if self._event_plan is not None else []
+
+    def _core_step(self, dev, noise_ids, touch):
+        plan = (
+            self._event_plan.with_touch(touch)
+            if self._event_plan is not None else None
+        )
+        return make_core_step(
+            dev=dev, noise_ids=noise_ids, event_plan=plan, **self._step_kw
         )
 
     def init_state(self, t0: int = 0) -> Dict:
@@ -785,9 +833,15 @@ class Simulator:
             tr_minus=jnp.zeros((n_p,), jnp.float32),
         )
 
-    @functools.partial(jax.jit, static_argnames=("self", "steps"))
     def run(self, state: Dict, steps: int):
-        return jax.lax.scan(self._step, state, None, length=steps)
+        return self._run(
+            self.dev, self._noise_ids, self._touch(), state, steps=steps
+        )
+
+    @functools.partial(jax.jit, static_argnames=("self", "steps"))
+    def _run(self, dev, noise_ids, touch, state: Dict, steps: int):
+        step = self._core_step(dev, noise_ids, touch)
+        return jax.lax.scan(step, state, None, length=steps)
 
     # -- dCSR sync (simulation state -> serializable network) -------------
     def state_to_dcsr(self, state: Dict) -> None:

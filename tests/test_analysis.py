@@ -35,7 +35,7 @@ def _toy_scan(n_collectives: int):
     over a 1-device parts mesh (the primitive is recorded in the jaxpr
     regardless of mesh size)."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from repro.compat import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("parts",))
 
@@ -50,7 +50,7 @@ def _toy_scan(n_collectives: int):
 
     return shard_map(
         fn, mesh=mesh, in_specs=P("parts"), out_specs=(P("parts"), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -84,7 +84,7 @@ def test_undeclared_exchange_key_fails():
 
 def test_disallowed_collective_kind_fails():
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from repro.compat import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("parts",))
 
@@ -94,7 +94,7 @@ def test_disallowed_collective_kind_fails():
     fn = shard_map(
         lambda x: jax.lax.scan(body, x, None, length=2),
         mesh=mesh, in_specs=P("parts"), out_specs=(P("parts"), P()),
-        check_rep=False,
+        check_vma=False,
     )
     contract = EngineContract("toy", {"dense": 1})  # allows all_gather
     problems = check_jaxpr_facts(
@@ -112,7 +112,7 @@ def test_float64_leak_fails_contract():
         wide = c.astype(jnp.float64) + 1.0  # the leak
         return wide.astype(jnp.float32), None
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         facts = jaxpr_facts(
             lambda x: jax.lax.scan(body, x, None, length=2),
             jax.ShapeDtypeStruct((4,), jnp.float32),

@@ -115,6 +115,19 @@ def test_keystream_ref_vs_device_words():
         np.testing.assert_array_equal(got, ref, err_msg=backend)
 
 
+@pytest.mark.parametrize("j0,n_words", [(0, 1), (1, 1), (3, 4), (2, 9)])
+def test_word_matrix_is_the_per_word_cipher(j0, n_words):
+    """Word ``w`` of a row is half ``w % 2`` of the cipher at counter
+    ``(row, w // 2)``, whatever window of words is asked for."""
+    rows = np.array([0, 3, 2**20], dtype=np.int64)
+    got = crng.word_matrix(7, 99, rows, j0, n_words)
+    for i, row in enumerate(rows):
+        for j in range(n_words):
+            w = j0 + j
+            halves = crng.threefry2x32(7, 99, np.uint32(row), np.uint32(w // 2))
+            assert got[i, j] == halves[w % 2], (row, w)
+
+
 @pytest.mark.parametrize("backend", ["ref", "pallas_interpret"])
 def test_network_ref_vs_device_path(backend):
     """Float assembly is host-side shared code; the device path only
@@ -183,3 +196,41 @@ def test_rule_spec_validation():
             "a", "b", kernel=DistanceKernel(0.5, 1.0)),))
     spec = RuleSpec(pops, (ConnectRule("a", "b", fan_in=2),), seed=9)
     assert spec.n == 20 and spec.offsets()["b"] == (10, 20)
+
+
+def test_build_path_choice_is_explicit(monkeypatch):
+    """'auto' is the host path on every platform, whatever the simulator
+    backend; a failing device keystream propagates instead of being
+    swapped for the host path."""
+    from repro.builder import procedural
+    from repro.kernels import ops
+
+    monkeypatch.setenv("REPRO_BACKEND", "pallas")
+    assert procedural.resolve_build_path("auto") == "ref"
+    with pytest.raises(ValueError, match="unknown build path"):
+        procedural.resolve_build_path("gpu")
+
+    def broken(*a, **k):
+        raise RuntimeError("keystream refused")
+
+    monkeypatch.setattr(ops, "builder_keystream", broken)
+    with pytest.raises(RuntimeError, match="keystream refused"):
+        build_network(balanced_ei_rules(n=40, seed=1), path="device")
+
+
+def test_microcircuit_rules_inhibitory_populations():
+    """Synapses from the four inhibitory populations (``L*I``) are
+    negative with the 0.8 ms delay; excitatory ones positive with 1.5 ms,
+    as in the eager ``microcircuit()``."""
+    spec = microcircuit_rules(scale=0.02, seed=1)
+    net = build_network(spec)
+    part = net.parts[0]
+    offs = spec.offsets()
+    inh = np.zeros(spec.n, bool)
+    for name, (a, b) in offs.items():
+        inh[a:b] = name.endswith("I")
+    src_inh = inh[part.col_idx]
+    w, d = part.edge_state[:, 0], part.edge_state[:, 1]
+    assert src_inh.any() and (~src_inh).any()
+    assert (w[src_inh] < 0).all() and (d[src_inh] == 8).all()
+    assert (w[~src_inh] > 0).all() and (d[~src_inh] == 15).all()

@@ -313,6 +313,35 @@ def test_resolve_backend_precedence(monkeypatch):
     assert dispatch.resolve_backend() == dispatch._platform_default()
 
 
+def test_sim_backend_is_ref_on_a_tpu_platform(monkeypatch):
+    """On TPU the simulators take the XLA-compiled 'ref' step; direct
+    kernel calls keep the compiled Pallas platform default."""
+    monkeypatch.delenv("REPRO_BACKEND", raising=False)
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
+    dispatch._platform_default.cache_clear()
+    try:
+        assert dispatch.resolve_sim_backend() == "ref"
+        assert dispatch.resolve_backend() == "pallas"
+        assert dispatch.resolve_sim_backend("pallas") == "pallas"
+    finally:
+        dispatch._platform_default.cache_clear()
+
+
+@pytest.mark.parametrize("fused", [None, False])
+def test_compiled_pallas_backend_raises_at_session(fused):
+    """An explicit compiled-Pallas backend is refused at construction,
+    naming the compiler's limit — for the fused engines and for the
+    unfused one (whose spike_gather kernel gathers the same way)."""
+    from repro.builder import microcircuit_rules
+    from repro.snn import Session, SimConfig
+
+    with pytest.raises(ValueError, match="Only 2D gather is supported"):
+        Session(
+            microcircuit_rules(scale=0.01),
+            SimConfig(backend="pallas", fused=fused),
+        )
+
+
 ELIGIBLE = dict(
     backend="pallas", models_present=("lif",), any_plastic=False,
     identity_exchange=True, identity_rows=True, n_delay_buckets=2,

@@ -141,3 +141,18 @@ def test_compat_shim_warns_and_matches():
             assert getattr(mod, name) is not None
     s = shim.analyze_hlo(MINI_HLO)
     assert s.collective_counts["all-reduce"] == 12
+
+
+def test_wide_ops_exempt_only_the_rng_counter():
+    """The threefry RNG's u64 counter iota (and the scalar u64 shift
+    literal beside it) is not a promotion; any other u64 result is."""
+    rng = (
+        'ENTRY %main () -> u64[8] {\n'
+        '  %c = u64[] constant(32), metadata={op_name="jit(f)/shard_map"}\n'
+        '  ROOT %i = u64[8]{0} iota(), iota_dimension=0, metadata={op_name='
+        '"jit(f)/jit(_uniform)/iota_2x32_shape"}\n'
+        '}\n'
+    )
+    assert wide_dtype_ops(rng) == []
+    leak = rng.replace("/iota_2x32_shape", "/add")
+    assert [(i, d) for _, i, d in wide_dtype_ops(leak)] == [("i", "u64")]
