@@ -40,7 +40,6 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import json
 import os
 import sys
 import time
@@ -464,28 +463,6 @@ def run_matrix(
     return violations, len(specs)
 
 
-def _merge_bench(path: str, wall_s: float, n_configs: int) -> None:
-    """Record the matrix's wall time in the benchmark report as an
-    informational entry: no ``us_per_step``, so the regression gate
-    (benchmarks/check_regression.py) never gates it — even --strict
-    ignores modes without a gated stat."""
-    data: Dict[str, Any] = {}
-    if os.path.exists(path):
-        try:
-            with open(path) as f:
-                data = json.load(f)
-        except (OSError, ValueError):
-            data = {}
-    data.setdefault("modes", {})["contract_check"] = dict(
-        metric="engine_contract_matrix_wall_s",
-        informational=True,
-        wall_s=round(wall_s, 3),
-        configs=n_configs,
-    )
-    with open(path, "w") as f:
-        json.dump(data, f, indent=2, sort_keys=True)
-
-
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m repro.analysis.contracts",
@@ -500,9 +477,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="skip the compile+HLO pass (jaxpr checks only)")
     ap.add_argument("--list", action="store_true",
                     help="print the matrix rows and exit")
-    ap.add_argument("--bench-json", default=None, metavar="PATH",
-                    help="merge the matrix wall time into this benchmark "
-                         "report (informational, ungated)")
     args = ap.parse_args(argv)
 
     specs = [
@@ -542,8 +516,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         specs, steps=args.steps, hlo=not args.no_hlo
     )
     wall = time.perf_counter() - t0
-    if args.bench_json:
-        _merge_bench(args.bench_json, wall, n)
     if violations:
         print(f"\n{len(violations)} contract violation(s):")
         for case, problem in violations:

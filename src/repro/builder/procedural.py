@@ -32,6 +32,7 @@ from typing import Optional
 
 import numpy as np
 
+from .. import obs
 from ..core.dcsr import DCSRNetwork, DCSRPartition
 from . import crng
 from .rules import ConnectRule, RuleSpec
@@ -426,6 +427,7 @@ def build_partition(
     )
 
 
+@obs.spanned(obs.BUILD_RULES)
 def build_network(
     spec: RuleSpec,
     k: int = 1,

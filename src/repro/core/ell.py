@@ -22,6 +22,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from .. import obs
 from .dcsr import DCSRPartition
 from .state import EDGE_WEIGHT, EDGE_DELAY
 
@@ -85,6 +86,7 @@ class DelayELL:
             b.weights = np.where(b.valid, np.asarray(w, np.float32), 0.0)
 
 
+@obs.spanned(obs.BUILD_ELL)
 def build_delay_ell(
     part: DCSRPartition,
     n_global: int,

@@ -46,6 +46,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..core.dcsr import DCSRNetwork, DCSRPartition
 from ..core.state import ModelRegistry
 from ..testing.faults import fault_point
@@ -125,10 +126,16 @@ class NetSnapshot:
     network: ``parts`` maps part_id -> the arrays its ``part<p>.npz``
     shard will hold (mutable state copied; immutable topology referenced),
     ``manifest`` is everything but the per-file CRCs (computed at write
-    time)."""
+    time).  ``copied_bytes`` counts the arrays the capture copied."""
 
     parts: List[Tuple[int, Dict[str, np.ndarray]]]
     manifest: Dict
+    copied_bytes: int = 0
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes of every array the shards hold."""
+        return obs.nbytes([arrs for _, arrs in self.parts])
 
 
 def snapshot_network(
@@ -148,6 +155,7 @@ def snapshot_network(
     race-free against continued simulation and a later ``sync_to_dcsr``.
     """
     parts: List[Tuple[int, Dict[str, np.ndarray]]] = []
+    copied = 0
     for part in net.parts:
         arrs = dict(
             row_ptr=part.row_ptr, col_idx=part.col_idx,
@@ -160,6 +168,9 @@ def snapshot_network(
         if sim_state and part.part_id in sim_state:
             for k, v in sim_state[part.part_id].items():
                 arrs[f"sim_{k}"] = np.array(v, copy=True)
+        copied += sum(v.nbytes for k, v in arrs.items()
+                      if k in ("vtx_state", "edge_state")
+                      or k.startswith("sim_"))
         parts.append((part.part_id, arrs))
     manifest = dict(
         format_version=f"{FORMAT_VERSION[0]}.{FORMAT_VERSION[1]}",
@@ -184,7 +195,8 @@ def snapshot_network(
     rs = getattr(net, "rule_spec", None)
     if rs is not None:
         manifest["rule_spec"] = rs
-    return NetSnapshot(parts=parts, manifest=manifest)
+    return NetSnapshot(parts=parts, manifest=manifest,
+                       copied_bytes=copied)
 
 
 def write_snapshot(
@@ -199,28 +211,31 @@ def write_snapshot(
     (by default one writer per partition, capped at the host's CPU
     count); the manifest — whose presence marks the snapshot complete —
     is written last, after every shard (and its CRC) landed."""
-    if atomic:
-        with atomic_dir(path) as tmp:
-            _write_snapshot_dir(snap, tmp, max_workers)
-        return
-    os.makedirs(path, exist_ok=True)
-    _write_snapshot_dir(snap, path, max_workers)
+    with obs.span(obs.WRITE, bytes=snap.nbytes):
+        if atomic:
+            with atomic_dir(path) as tmp:
+                _write_snapshot_dir(snap, tmp, max_workers)
+            return
+        os.makedirs(path, exist_ok=True)
+        _write_snapshot_dir(snap, path, max_workers)
 
 
 def _write_part(path: str, item: Tuple[int, Dict[str, np.ndarray]]):
     part_id, arrs = item
     fn = f"part{part_id}.npz"
     full = os.path.join(path, fn)
-    # serialize to memory first: the CRC is computed from the buffer the
-    # verified write checks the disk against, so a torn/bit-rotted write
-    # can never be recorded in the manifest as the shard's "good" CRC
-    buf = io.BytesIO()
-    np.savez(buf, **arrs)
-    try:
-        # getbuffer(): a view, not a second copy of a multi-GB shard
-        crc = write_bytes_verified(full, buf.getbuffer(), "shard_write")
-    except OSError as e:
-        raise ShardWriteError(part_id, full, e) from e
+    with obs.span(obs.WRITE_PART, bytes=obs.nbytes(arrs)):
+        # serialize to memory first: the CRC is computed from the buffer
+        # the verified write checks the disk against, so a torn/bit-rotted
+        # write can never be recorded in the manifest as the shard's
+        # "good" CRC
+        buf = io.BytesIO()
+        np.savez(buf, **arrs)
+        try:
+            # getbuffer(): a view, not a second copy of a multi-GB shard
+            crc = write_bytes_verified(full, buf.getbuffer(), "shard_write")
+        except OSError as e:
+            raise ShardWriteError(part_id, full, e) from e
     return fn, crc
 
 

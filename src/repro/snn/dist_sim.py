@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import obs
 from ..compat import shard_map
 from ..launch.mesh import make_snn_mesh
 
@@ -331,15 +332,16 @@ class DistSimulator:
     def init_state(self, t0: int = 0) -> Dict:
         s = self.stacked
         k, n_p, D = s.k, s.n_p, s.d_ring
-        return dict(
-            t=jnp.asarray(t0, jnp.int32),
-            vtx_state=jnp.asarray(s.vtx_state0),
-            ring=jnp.zeros((k, D, n_p), jnp.float32),
-            hist=jnp.zeros((k, D, n_p), jnp.uint8),
-            weights=tuple(jnp.asarray(w) for w in s.weights),
-            tr_plus=jnp.zeros((k, n_p), jnp.float32),
-            tr_minus=jnp.zeros((k, n_p), jnp.float32),
-        )
+        with obs.span(obs.BUILD_PLACE):
+            return dict(
+                t=jnp.asarray(t0, jnp.int32),
+                vtx_state=jnp.asarray(s.vtx_state0),
+                ring=jnp.zeros((k, D, n_p), jnp.float32),
+                hist=jnp.zeros((k, D, n_p), jnp.uint8),
+                weights=tuple(jnp.asarray(w) for w in s.weights),
+                tr_plus=jnp.zeros((k, n_p), jnp.float32),
+                tr_minus=jnp.zeros((k, n_p), jnp.float32),
+            )
 
     def _specs(self):
         """PartitionSpecs for the carry pytree (leading axis = parts,
@@ -482,9 +484,10 @@ class DistSimulator:
             if self._device_args is None:
                 # the constants go to their devices once, partition p to
                 # mesh device p, and are reused by every chunk program
-                self._device_args = jax.device_put(
-                    args, NamedSharding(self.mesh, P("parts"))
-                )
+                with obs.span(obs.BUILD_PLACE):
+                    self._device_args = jax.device_put(
+                        args, NamedSharding(self.mesh, P("parts"))
+                    )
             self._compiled[steps] = jax.jit(fn)
         return self._compiled[steps](*self._device_args, state)
 

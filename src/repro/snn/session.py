@@ -111,6 +111,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.dcsr import DCSRNetwork, merge_to_single
 from ..core.partition import block_partition
 from ..io.async_writer import AsyncWriter
@@ -170,15 +171,17 @@ class _SingleEngine:
         return self.sim.init_state(t0)
 
     def run_chunk(self, state: Dict, steps: int) -> Tuple[Dict, Dict]:
-        state, outs = self.sim.run(state, steps)
-        host = dict(
-            spike_count=np.asarray(outs["spike_count"]).astype(np.int32),
-            overflow=np.asarray(outs["overflow"]).astype(np.int32),
-        )
-        if "raster" in outs:
-            host["raster"] = np.asarray(outs["raster"])
-        if "v_mean" in outs:
-            host["v_mean"] = np.asarray(outs["v_mean"])
+        with obs.span(obs.DISPATCH):
+            state, outs = self.sim.run(state, steps)
+        with obs.span(obs.FETCH):
+            host = dict(
+                spike_count=np.asarray(outs["spike_count"]).astype(np.int32),
+                overflow=np.asarray(outs["overflow"]).astype(np.int32),
+            )
+            if "raster" in outs:
+                host["raster"] = np.asarray(outs["raster"])
+            if "v_mean" in outs:
+                host["v_mean"] = np.asarray(outs["v_mean"])
         return state, host
 
     def sync_to_dcsr(self, state: Dict) -> None:
@@ -221,21 +224,23 @@ class _SPMDEngine:
         return self.sim.init_state(t0)
 
     def run_chunk(self, state: Dict, steps: int) -> Tuple[Dict, Dict]:
-        state, outs = self.sim.run(state, steps)
-        sc = np.asarray(outs["spike_count"])  # (steps, k)
-        host = dict(
-            spike_count=sc.sum(axis=1).astype(np.int32),
-            overflow=np.asarray(outs["overflow"]).sum(axis=1).astype(
-                np.int32
-            ),
-        )
-        if "raster" in outs:
-            r = np.asarray(outs["raster"])  # (steps, k, n_p)
-            host["raster"] = r.reshape(r.shape[0], -1)
-        if "v_mean" in outs:
-            host["v_mean"] = (
-                np.asarray(outs["v_mean"]).mean(axis=1).astype(np.float32)
+        with obs.span(obs.DISPATCH):
+            state, outs = self.sim.run(state, steps)
+        with obs.span(obs.FETCH):
+            sc = np.asarray(outs["spike_count"])  # (steps, k)
+            host = dict(
+                spike_count=sc.sum(axis=1).astype(np.int32),
+                overflow=np.asarray(outs["overflow"]).sum(axis=1).astype(
+                    np.int32
+                ),
             )
+            if "raster" in outs:
+                r = np.asarray(outs["raster"])  # (steps, k, n_p)
+                host["raster"] = r.reshape(r.shape[0], -1)
+            if "v_mean" in outs:
+                host["v_mean"] = (
+                    np.asarray(outs["v_mean"]).mean(axis=1).astype(np.float32)
+                )
         return state, host
 
     def sync_to_dcsr(self, state: Dict) -> None:
@@ -328,9 +333,10 @@ class Session:
                 "use Session.restore(path, k=...) for snapshots"
             )
         elif isinstance(net_or_path, (str, os.PathLike)):
-            net, sim_state, t_now = load_latest_valid(
-                os.fspath(net_or_path)
-            )
+            with obs.span(obs.RESTORE_READ):
+                net, sim_state, t_now = load_latest_valid(
+                    os.fspath(net_or_path)
+                )
         elif isinstance(net_or_path, DCSRNetwork):
             net, sim_state, t_now = net_or_path, None, 0
         else:
@@ -352,7 +358,6 @@ class Session:
         self._state: Optional[Dict] = None
         self._t0 = int(t_now)
         self._pending_runtime = sim_state if sim_state else None
-        self.last_run_chunks: Tuple[int, ...] = ()
         # gather='auto' starts on the dense sweep; run()'s chunk loop swaps
         # to the event engine (and back) from the observed spike rate
         self._gather_mode = (
@@ -578,10 +583,12 @@ class Session:
             c = min(chunk_size, steps - done)
             if next_ckpt is not None:
                 c = min(c, next_ckpt - done)
-            state, outs = engine.run_chunk(self._state, c)
+            with obs.span(obs.CHUNK):
+                state, outs = engine.run_chunk(self._state, c)
             self._state = state
-            for mon in monitors:
-                mon.on_chunk(t_run0 + done, outs)
+            with obs.span(obs.READOUT):
+                for mon in monitors:
+                    mon.on_chunk(t_run0 + done, outs)
             counts.append(outs["spike_count"])
             overflows.append(outs["overflow"])
             chunks.append(c)
@@ -604,39 +611,15 @@ class Session:
                     engine = self._engine(rec_raster, rec_v)
             if next_ckpt is not None and done == next_ckpt:
                 t_ck = time.perf_counter()
-                try:
-                    self.save(
-                        os.path.join(
-                            checkpoint_dir, f"step_{t_run0 + done:08d}"
-                        ),
-                        wait=checkpoint_sync,
+                with obs.span(obs.CKPT):
+                    self._checkpoint(
+                        checkpoint_dir, t_run0 + done, max_to_keep,
+                        checkpoint_sync,
                     )
-                except OSError as e:
-                    with self._ckpt_mark_lock:
-                        last = self._last_good_ckpt_step
-                    raise OSError(
-                        f"checkpoint at step {t_run0 + done} failed "
-                        "(writer retries exhausted); last successful "
-                        "checkpoint: "
-                        + (f"step {last}" if last is not None else
-                           "none from this session")
-                        + " — that is your rollback point"
-                    ) from e
-                if max_to_keep:
-                    # retention rides the same FIFO queue as the writes,
-                    # so GC can never run ahead of an in-flight older step
-                    if checkpoint_sync:
-                        self._gc_checkpoints(checkpoint_dir, max_to_keep)
-                    else:
-                        self._writer_obj().submit(
-                            self._gc_checkpoints, checkpoint_dir,
-                            max_to_keep,
-                        )
                 stalls.append(time.perf_counter() - t_ck)
                 next_ckpt += checkpoint_every
         for mon in monitors:
             mon.finalize()
-        self.last_run_chunks = tuple(chunks)
         self.last_gather_modes = tuple(gather_modes)
         if checkpoint_every is not None:
             self.last_ckpt_stalls = tuple(stalls)
@@ -659,6 +642,34 @@ class Session:
             chunks=tuple(chunks),
             overflow=overflow,
         )
+
+    def _checkpoint(self, root: str, step: int,
+                    max_to_keep: Optional[int], sync: bool) -> None:
+        """One checkpoint boundary of ``run``: save ``root/step_XXXXXXXX``
+        and queue the retention GC behind it."""
+        try:
+            self.save(os.path.join(root, f"step_{step:08d}"), wait=sync)
+        except OSError as e:
+            with self._ckpt_mark_lock:
+                last = self._last_good_ckpt_step
+            raise OSError(
+                f"checkpoint at step {step} failed "
+                "(writer retries exhausted); last successful "
+                "checkpoint: "
+                + (f"step {last}" if last is not None else
+                   "none from this session")
+                + " — that is your rollback point"
+            ) from e
+        if max_to_keep:
+            # retention rides the same FIFO queue as the writes, so GC
+            # can never run ahead of an in-flight older step
+            if sync:
+                self._gc_checkpoints(root, max_to_keep)
+            else:
+                with obs.span(obs.CKPT_ENQUEUE):
+                    self._writer_obj().submit(
+                        self._gc_checkpoints, root, max_to_keep,
+                    )
 
     def run_supervised(
         self,
@@ -750,14 +761,19 @@ class Session:
         self._ensure_state(eng)
         if self._writer is not None:
             self._writer.check()  # surface earlier background failures
-        eng.sync_to_dcsr(self._state)
+        with obs.span(obs.CKPT_SYNC, bytes=obs.nbytes(
+                self._state["vtx_state"], self._state["weights"])):
+            eng.sync_to_dcsr(self._state)
         step = self.t
-        snap = snapshot_network(
-            self.net, eng.runtime_state(self._state), step
-        )
+        with obs.span(obs.CKPT_CAPTURE) as sp:
+            snap = snapshot_network(
+                self.net, eng.runtime_state(self._state), step
+            )
+            sp.set_metadata(bytes=snap.copied_bytes)
         w = self._writer_obj()
-        w.submit(self._write_and_mark, snap, path, step,
-                 context=dict(step=step, path=path))
+        with obs.span(obs.CKPT_ENQUEUE):
+            w.submit(self._write_and_mark, snap, path, step,
+                     context=dict(step=step, path=path))
         if wait:
             w.wait()
         return path
@@ -842,18 +858,20 @@ class Session:
                 k=1 if (k == 1 and assignment is None) else None,
                 chunk_rows=chunk_rows or DEFAULT_CHUNK_ROWS,
             )
+        else:
+            loader = None
+        with obs.span(obs.RESTORE_READ):
             net, sim_state, t_now = load_latest_valid(
                 os.fspath(path), loader=loader
             )
-        else:
-            net, sim_state, t_now = load_latest_valid(os.fspath(path))
         if assignment is not None or (k is not None and k != net.k):
             asn = (
                 np.asarray(assignment, np.int64)
                 if assignment is not None
                 else block_partition(net.n, k)
             )
-            net, sim_state = reshard_sim_state(net, sim_state, asn)
+            with obs.span(obs.RESTORE_RESHARD):
+                net, sim_state = reshard_sim_state(net, sim_state, asn)
         ses = cls(net, cfg, engine=engine, mesh=mesh)
         ses._t0 = int(t_now)
         ses._pending_runtime = sim_state if sim_state else None

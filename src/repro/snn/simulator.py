@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.dcsr import DCSRNetwork, DCSRPartition
 from ..core.ell import DelayELL, build_delay_ell
 from ..core.state import EDGE_WEIGHT
@@ -181,6 +182,7 @@ class PartitionDeviceData:
     weights_remote: Optional[List[jnp.ndarray]] = None
 
 
+@obs.spanned(obs.BUILD_PLACE)
 def partition_device_data(
     part: DCSRPartition,
     net: DCSRNetwork,
@@ -402,67 +404,75 @@ def make_core_step(
         return ring, weights
 
     def step(carry, _):
+        # every op of the step sits in one obs scope (its top-level name
+        # in the op metadata): noise, neuron, exchange, deliver, stdp
         t = carry["t"]
-        slot = jnp.mod(t, D)
         if choice.overlap == "double_buffer":
             # flush step t-1's deferred remote gather before this step
             # reads or clears any slot (a delay-1 contribution from t-1
             # lands in exactly the slot delivered now)
-            ring0, weights0 = _apply_pending(
-                carry["ring"], carry["weights"], carry["_pending"]
-            )
+            with obs.scope(obs.DELIVER):
+                ring0, weights0 = _apply_pending(
+                    carry["ring"], carry["weights"], carry["_pending"]
+                )
         else:
             ring0, weights0 = carry["ring"], carry["weights"]
         new_pending = None
-        i_syn = jax.lax.dynamic_index_in_dim(
-            ring0, slot, axis=0, keepdims=False
-        )
-        if not (choice.split or choice.event):
-            # the split/event post-exchange kernels rotate the ring
-            # themselves; the other engines clear the delivered slot here
-            ring = jax.lax.dynamic_update_index_in_dim(
-                ring0, jnp.zeros((ring0.shape[1],), ring0.dtype),
-                slot, axis=0,
+        with obs.scope(obs.NEURON):
+            slot = jnp.mod(t, D)
+            i_syn = jax.lax.dynamic_index_in_dim(
+                ring0, slot, axis=0, keepdims=False
             )
+            if not (choice.split or choice.event):
+                # the split/event post-exchange kernels rotate the ring
+                # themselves; the other engines clear the delivered slot
+                ring = jax.lax.dynamic_update_index_in_dim(
+                    ring0, jnp.zeros((ring0.shape[1],), ring0.dtype),
+                    slot, axis=0,
+                )
         # deterministic noise keyed by (seed, t, permanent neuron id)
-        if noise_sigma > 0:
-            key_t = jax.random.fold_in(base_key, t)
-            noise_g = noise_sigma * jax.random.normal(
-                key_t, (n_global,), dtype=jnp.float32
-            )
-            noise = jnp.take(noise_g, noise_ids, axis=0)
-        else:
-            noise = jnp.zeros((n_p,), jnp.float32)
+        with obs.scope(obs.NOISE):
+            if noise_sigma > 0:
+                key_t = jax.random.fold_in(base_key, t)
+                noise_g = noise_sigma * jax.random.normal(
+                    key_t, (n_global,), dtype=jnp.float32
+                )
+                noise = jnp.take(noise_g, noise_ids, axis=0)
+            else:
+                noise = jnp.zeros((n_p,), jnp.float32)
 
         overflow = jnp.zeros((), jnp.int32)
         if choice.split or choice.event:
             # the split/event engines precompute the slot arithmetic into
             # masks so their post-exchange kernel needs no dynamic indexing
             # — the write rows are data, not control flow
-            d_rows = jnp.arange(D)
-            clear_mask = (d_rows != slot).astype(jnp.float32)
-            write_slots = jnp.stack(
-                [jnp.mod(t + d, D) for d in dev.delays]
-            )
-            write_onehot = (
-                write_slots[:, None] == d_rows[None, :]
-            ).astype(jnp.float32)
+            with obs.scope(obs.DELIVER):
+                d_rows = jnp.arange(D)
+                clear_mask = (d_rows != slot).astype(jnp.float32)
+                write_slots = jnp.stack(
+                    [jnp.mod(t + d, D) for d in dev.delays]
+                )
+                write_onehot = (
+                    write_slots[:, None] == d_rows[None, :]
+                ).astype(jnp.float32)
+        if choice.fused:
+            # the fused kernels take the summed input current; each kernel
+            # that traverses the synapse panels is scoped as delivery
+            vtx = carry["vtx_state"]
+            with obs.scope(obs.NEURON):
+                i_tot = i_syn + noise + vtx[:, LIF_BIAS]
         if choice.engine == "fused":
             # one Pallas launch: LIF advance + spike emission + per-bucket
             # gather; the spike vector never round-trips through HBM
             # between emission and propagation (identity exchange)
-            vtx = carry["vtx_state"]
-            i_tot = i_syn + noise + vtx[:, LIF_BIAS]
-            v2, r2, spikes, currents = ops.fused_step(
-                vtx[:, LIF_V], vtx[:, LIF_REF], i_tot,
-                dev.cols, weights0,
-                params=lif_params, backend=backend,
-            )
-            vtx_state = (
-                vtx.at[:, LIF_V].set(v2).at[:, LIF_REF].set(r2)
-            )
-            for i, d in enumerate(dev.delays):
-                ring = ring.at[jnp.mod(t + d, D)].add(currents[i][:n_p])
+            with obs.scope(obs.DELIVER):
+                v2, r2, spikes, currents = ops.fused_step(
+                    vtx[:, LIF_V], vtx[:, LIF_REF], i_tot,
+                    dev.cols, weights0,
+                    params=lif_params, backend=backend,
+                )
+                for i, d in enumerate(dev.delays):
+                    ring = ring.at[jnp.mod(t + d, D)].add(currents[i][:n_p])
             new_weights = weights0
             tr_plus, tr_minus = carry["tr_plus"], carry["tr_minus"]
         elif choice.engine == "fused_plastic":
@@ -471,38 +481,30 @@ def make_core_step(
             # ONCE — the gather reads the pre-update weights and the
             # plastic-masked update writes back in the same grid step
             # (identity exchange: act == spikes, pre-trace == tr_plus')
-            vtx = carry["vtx_state"]
-            i_tot = i_syn + noise + vtx[:, LIF_BIAS]
-            (v2, r2, spikes, tr_plus, tr_minus, currents,
-             new_weights) = ops.fused_step_plastic(
-                vtx[:, LIF_V], vtx[:, LIF_REF], i_tot,
-                carry["tr_plus"], carry["tr_minus"],
-                dev.cols, weights0, dev.plastic,
-                params=lif_params, taus=(tau_plus, tau_minus),
-                stdp=stdp_params, backend=backend,
-            )
-            vtx_state = (
-                vtx.at[:, LIF_V].set(v2).at[:, LIF_REF].set(r2)
-            )
-            for i, d in enumerate(dev.delays):
-                ring = ring.at[jnp.mod(t + d, D)].add(currents[i][:n_p])
+            with obs.scope(obs.DELIVER):
+                (v2, r2, spikes, tr_plus, tr_minus, currents,
+                 new_weights) = ops.fused_step_plastic(
+                    vtx[:, LIF_V], vtx[:, LIF_REF], i_tot,
+                    carry["tr_plus"], carry["tr_minus"],
+                    dev.cols, weights0, dev.plastic,
+                    params=lif_params, taus=(tau_plus, tau_minus),
+                    stdp=stdp_params, backend=backend,
+                )
+                for i, d in enumerate(dev.delays):
+                    ring = ring.at[jnp.mod(t + d, D)].add(currents[i][:n_p])
             new_weights = tuple(new_weights)
         elif choice.engine == "fused_split_plastic":
             # plastic split step: the pre-exchange kernel advances LIF AND
             # the e-traces, the exchange carries spikes + pre-traces, and
             # the post-exchange kernel folds ring rotate + all gathers +
             # the STDP weight update into one pass over the panels
-            vtx = carry["vtx_state"]
-            i_tot = i_syn + noise + vtx[:, LIF_BIAS]
-            v2, r2, spikes, tr_plus, tr_minus = ops.fused_pre_exchange(
-                vtx[:, LIF_V], vtx[:, LIF_REF], i_tot,
-                carry["tr_plus"], carry["tr_minus"],
-                params=lif_params, taus=(tau_plus, tau_minus),
-                backend=backend,
-            )
-            vtx_state = (
-                vtx.at[:, LIF_V].set(v2).at[:, LIF_REF].set(r2)
-            )
+            with obs.scope(obs.NEURON):
+                v2, r2, spikes, tr_plus, tr_minus = ops.fused_pre_exchange(
+                    vtx[:, LIF_V], vtx[:, LIF_REF], i_tot,
+                    carry["tr_plus"], carry["tr_minus"],
+                    params=lif_params, taus=(tau_plus, tau_minus),
+                    backend=backend,
+                )
             if overlap_on:
                 # plastic panels are never split (weights are state):
                 # the local pass gathers the full panels against the own
@@ -511,167 +513,152 @@ def make_core_step(
                 # it; the remote pass carries the STDP update (elementwise
                 # in the full act/pre-trace, so weights stay bit-exact
                 # against the serialized engine)
-                act_local = overlap_ctx["embed"](
-                    overlap_ctx["local"](spikes)
-                )
-                act, pre_trace, overflow = exchange(spikes, tr_plus)
-                ring = ops.fused_post_exchange_local(
-                    act_local, ring0, clear_mask, write_onehot,
-                    dev.cols, weights0, backend=backend,
-                )
-                if choice.overlap == "double_buffer":
-                    new_pending = dict(
-                        valid=jnp.ones((), jnp.int32),
-                        onehot=write_onehot, act=act,
-                        pre_trace=pre_trace, post_trace=tr_minus,
-                        post_spike=spikes,
+                with obs.scope(obs.DELIVER):
+                    act_local = overlap_ctx["embed"](
+                        overlap_ctx["local"](spikes)
                     )
-                    new_weights = weights0  # updated at the t+1 flush
-                else:
-                    act_remote = overlap_ctx["mask_remote"](act)
-                    ring, new_weights = (
-                        ops.fused_post_exchange_remote_plastic(
-                            act_remote, act, pre_trace, ring,
-                            write_onehot, tr_minus, spikes,
-                            dev.cols, weights0, dev.plastic,
-                            stdp=stdp_params, backend=backend,
+                with obs.scope(obs.EXCHANGE):
+                    act, pre_trace, overflow = exchange(spikes, tr_plus)
+                with obs.scope(obs.DELIVER):
+                    ring = ops.fused_post_exchange_local(
+                        act_local, ring0, clear_mask, write_onehot,
+                        dev.cols, weights0, backend=backend,
+                    )
+                    if choice.overlap == "double_buffer":
+                        new_pending = dict(
+                            valid=jnp.ones((), jnp.int32),
+                            onehot=write_onehot, act=act,
+                            pre_trace=pre_trace, post_trace=tr_minus,
+                            post_spike=spikes,
                         )
-                    )
-                    new_weights = tuple(new_weights)
+                        new_weights = weights0  # updated at the t+1 flush
+                    else:
+                        act_remote = overlap_ctx["mask_remote"](act)
+                        ring, new_weights = (
+                            ops.fused_post_exchange_remote_plastic(
+                                act_remote, act, pre_trace, ring,
+                                write_onehot, tr_minus, spikes,
+                                dev.cols, weights0, dev.plastic,
+                                stdp=stdp_params, backend=backend,
+                            )
+                        )
+                        new_weights = tuple(new_weights)
             else:
-                act, pre_trace, overflow = exchange(spikes, tr_plus)
-                ring, new_weights = ops.fused_post_exchange_plastic(
-                    act, pre_trace, ring0, clear_mask, write_onehot,
-                    tr_minus, spikes, dev.cols, weights0, dev.plastic,
-                    stdp=stdp_params, backend=backend,
-                )
+                with obs.scope(obs.EXCHANGE):
+                    act, pre_trace, overflow = exchange(spikes, tr_plus)
+                with obs.scope(obs.DELIVER):
+                    ring, new_weights = ops.fused_post_exchange_plastic(
+                        act, pre_trace, ring0, clear_mask, write_onehot,
+                        tr_minus, spikes, dev.cols, weights0, dev.plastic,
+                        stdp=stdp_params, backend=backend,
+                    )
                 new_weights = tuple(new_weights)
-        elif choice.engine == "fused_split":
+        elif choice.engine == "fused_split" or choice.event:
             # the same fusion split at the exchange: fused {LIF + emit}
             # kernel, the collective, then a fused {ring rotate + every
             # delay-bucket gather} kernel — state arrays and the exchanged
-            # activity vector each cross HBM exactly once per step
-            vtx = carry["vtx_state"]
-            i_tot = i_syn + noise + vtx[:, LIF_BIAS]
-            v2, r2, spikes = ops.fused_pre_exchange(
-                vtx[:, LIF_V], vtx[:, LIF_REF], i_tot,
-                params=lif_params, backend=backend,
-            )
-            vtx_state = (
-                vtx.at[:, LIF_V].set(v2).at[:, LIF_REF].set(r2)
-            )
+            # activity vector each cross HBM exactly once per step.  The
+            # event variants compress the activity to spike ids on-device
+            # and gather ONLY synapse row blocks flagged as touched by an
+            # active presynaptic id — bit-equal to the dense sweep
+            # (fused_event: identity exchange, the activity is the
+            # partition's own spike vector)
+            with obs.scope(obs.NEURON):
+                v2, r2, spikes = ops.fused_pre_exchange(
+                    vtx[:, LIF_V], vtx[:, LIF_REF], i_tot,
+                    params=lif_params, backend=backend,
+                )
             if overlap_on:
                 # the collective is issued first in program order; the
                 # local gather that follows reads only the own spike
                 # vector and the build-time local sub-panels, so XLA's
-                # latency hiding runs it under the all-gather
-                act_local = overlap_ctx["local"](spikes)
-                act, _, overflow = exchange(spikes, carry["tr_plus"])
-                ring = ops.fused_post_exchange_local(
-                    act_local, ring0, clear_mask, write_onehot,
-                    dev.cols_local, dev.weights_local, backend=backend,
-                )
-                if choice.overlap == "double_buffer":
-                    new_pending = dict(
-                        valid=jnp.ones((), jnp.int32),
-                        onehot=write_onehot, act=act,
+                # latency hiding runs it under the all-gather (the event
+                # variants gather the small local sub-panels densely and
+                # compress the remote ids only, so the touched-block flags
+                # never wait on the own slice)
+                with obs.scope(obs.DELIVER):
+                    act_local = overlap_ctx["local"](spikes)
+                with obs.scope(obs.EXCHANGE):
+                    act, _, overflow = exchange(spikes, carry["tr_plus"])
+                with obs.scope(obs.DELIVER):
+                    ring = ops.fused_post_exchange_local(
+                        act_local, ring0, clear_mask, write_onehot,
+                        dev.cols_local, dev.weights_local, backend=backend,
                     )
-                else:
-                    ring = ops.fused_post_exchange_remote(
-                        act, ring, write_onehot,
-                        dev.cols_remote, dev.weights_remote,
-                        backend=backend,
-                    )
+                    if choice.overlap == "double_buffer":
+                        new_pending = dict(
+                            valid=jnp.ones((), jnp.int32),
+                            onehot=write_onehot, act=act,
+                        )
+                    elif choice.event:
+                        act_remote = overlap_ctx["mask_remote"](act)
+                        sel, flags = event_plan.select(act_remote)
+                        ring = ops.event_post_exchange(
+                            act_remote, ring, jnp.ones((D,), jnp.float32),
+                            write_onehot, sel, flags,
+                            dev.cols, weights0, backend=backend,
+                        )
+                    else:
+                        ring = ops.fused_post_exchange_remote(
+                            act, ring, write_onehot,
+                            dev.cols_remote, dev.weights_remote,
+                            backend=backend,
+                        )
             else:
-                act, _, overflow = exchange(spikes, carry["tr_plus"])
-                ring = ops.fused_post_exchange(
-                    act, ring0, clear_mask, write_onehot,
-                    dev.cols, weights0, backend=backend,
-                )
-            new_weights = weights0
-            tr_plus, tr_minus = carry["tr_plus"], carry["tr_minus"]
-        elif choice.event:
-            # event-driven gather: fused {LIF + emit}, the exchange, then
-            # the activity vector is compressed to spike ids on-device and
-            # the post-exchange kernel gathers ONLY synapse row blocks
-            # flagged as touched by an active presynaptic id — bit-equal
-            # to the dense sweep (fused_event: identity exchange, the
-            # activity is the partition's own spike vector)
-            vtx = carry["vtx_state"]
-            i_tot = i_syn + noise + vtx[:, LIF_BIAS]
-            v2, r2, spikes = ops.fused_pre_exchange(
-                vtx[:, LIF_V], vtx[:, LIF_REF], i_tot,
-                params=lif_params, backend=backend,
-            )
-            vtx_state = (
-                vtx.at[:, LIF_V].set(v2).at[:, LIF_REF].set(r2)
-            )
-            if overlap_on:
-                # local sub-panels are gathered densely (they are small
-                # and available before the collective); the event-driven
-                # compression applies to the remote ids only, so the
-                # touched-block flags never wait on the own slice
-                act_local = overlap_ctx["local"](spikes)
-                act, _, overflow = exchange(spikes, carry["tr_plus"])
-                ring = ops.fused_post_exchange_local(
-                    act_local, ring0, clear_mask, write_onehot,
-                    dev.cols_local, dev.weights_local, backend=backend,
-                )
-                if choice.overlap == "double_buffer":
-                    new_pending = dict(
-                        valid=jnp.ones((), jnp.int32),
-                        onehot=write_onehot, act=act,
-                    )
-                else:
-                    act_remote = overlap_ctx["mask_remote"](act)
-                    sel, flags = event_plan.select(act_remote)
-                    ring = ops.event_post_exchange(
-                        act_remote, ring, jnp.ones((D,), jnp.float32),
-                        write_onehot, sel, flags,
-                        dev.cols, weights0, backend=backend,
-                    )
-            else:
-                act, _, overflow = exchange(spikes, carry["tr_plus"])
-                sel, flags = event_plan.select(act)
-                ring = ops.event_post_exchange(
-                    act, ring0, clear_mask, write_onehot, sel, flags,
-                    dev.cols, weights0, backend=backend,
-                )
+                with obs.scope(obs.EXCHANGE):
+                    act, _, overflow = exchange(spikes, carry["tr_plus"])
+                with obs.scope(obs.DELIVER):
+                    if choice.event:
+                        sel, flags = event_plan.select(act)
+                        ring = ops.event_post_exchange(
+                            act, ring0, clear_mask, write_onehot, sel,
+                            flags, dev.cols, weights0, backend=backend,
+                        )
+                    else:
+                        ring = ops.fused_post_exchange(
+                            act, ring0, clear_mask, write_onehot,
+                            dev.cols, weights0, backend=backend,
+                        )
             new_weights = weights0
             tr_plus, tr_minus = carry["tr_plus"], carry["tr_minus"]
         else:
-            vtx_state, spikes = neuron_step(
-                dev.vtx_model, carry["vtx_state"], i_syn + noise
-            )
+            with obs.scope(obs.NEURON):
+                vtx_state, spikes = neuron_step(
+                    dev.vtx_model, carry["vtx_state"], i_syn + noise
+                )
+                if any_plastic:
+                    tr_plus = carry["tr_plus"] * jnp.exp(
+                        -dt / tau_plus
+                    ).astype(jnp.float32) + spikes
+                    tr_minus = carry["tr_minus"] * jnp.exp(
+                        -dt / tau_minus
+                    ).astype(jnp.float32) + spikes
+                else:
+                    tr_plus = carry["tr_plus"]
+                    tr_minus = carry["tr_minus"]
 
-            if any_plastic:
-                tr_plus = carry["tr_plus"] * jnp.exp(
-                    -dt / tau_plus
-                ).astype(jnp.float32) + spikes
-                tr_minus = carry["tr_minus"] * jnp.exp(
-                    -dt / tau_minus
-                ).astype(jnp.float32) + spikes
-            else:
-                tr_plus = carry["tr_plus"]
-                tr_minus = carry["tr_minus"]
-
-            act, pre_trace, overflow = exchange(spikes, tr_plus)
+            with obs.scope(obs.EXCHANGE):
+                act, pre_trace, overflow = exchange(spikes, tr_plus)
 
             weights = weights0
             new_weights = []
             for i, d in enumerate(dev.delays):
-                cur = ops.spike_gather(
-                    act, dev.cols[i], weights[i], backend=backend
-                )
-                if dev.identity_rows[i]:
-                    cur_rows = cur[:n_p]
-                else:
-                    cur_rows = jax.ops.segment_sum(
-                        cur, dev.row_maps[i], num_segments=n_p
+                with obs.scope(obs.DELIVER), obs.delay_scope(d):
+                    cur = ops.spike_gather(
+                        act, dev.cols[i], weights[i], backend=backend
                     )
-                wslot = jnp.mod(t + d, D)
-                ring = ring.at[wslot].add(cur_rows)
-                if any_plastic:
+                    if dev.identity_rows[i]:
+                        cur_rows = cur[:n_p]
+                    else:
+                        cur_rows = jax.ops.segment_sum(
+                            cur, dev.row_maps[i], num_segments=n_p
+                        )
+                    wslot = jnp.mod(t + d, D)
+                    ring = ring.at[wslot].add(cur_rows)
+                if not any_plastic:
+                    new_weights.append(weights[i])
+                    continue
+                with obs.scope(obs.STDP), obs.delay_scope(d):
                     pad_r = dev.cols[i].shape[0] - n_p
                     post_t = jnp.pad(tr_minus, (0, pad_r)) if pad_r \
                         else tr_minus
@@ -686,33 +673,38 @@ def make_core_step(
                             params=stdp_params, backend=backend,
                         )
                     )
-                else:
-                    new_weights.append(weights[i])
             new_weights = tuple(new_weights)
 
-        hist = jax.lax.dynamic_update_index_in_dim(
-            carry["hist"], spikes.astype(jnp.uint8), slot, axis=0
-        )
-        new_carry = dict(
-            t=t + 1, vtx_state=vtx_state, ring=ring, hist=hist,
-            weights=new_weights, tr_plus=tr_plus, tr_minus=tr_minus,
-        )
-        if choice.overlap == "double_buffer":
-            new_carry["_pending"] = (
-                new_pending if new_pending is not None else _pending_init()
+        with obs.scope(obs.NEURON):
+            if choice.fused:
+                vtx_state = vtx.at[:, LIF_V].set(v2).at[:, LIF_REF].set(r2)
+            hist = jax.lax.dynamic_update_index_in_dim(
+                carry["hist"], spikes.astype(jnp.uint8), slot, axis=0
             )
-        out = dict(spike_count=jnp.sum(spikes), overflow=overflow)
-        if record_raster:
-            out["raster"] = spikes.astype(jnp.uint8)
-        if record_v:
-            out["v_mean"] = jnp.mean(vtx_state[:, 0])
+            new_carry = dict(
+                t=t + 1, vtx_state=vtx_state, ring=ring, hist=hist,
+                weights=new_weights, tr_plus=tr_plus, tr_minus=tr_minus,
+            )
+            if choice.overlap == "double_buffer":
+                new_carry["_pending"] = (
+                    new_pending if new_pending is not None
+                    else _pending_init()
+                )
+            out = dict(spike_count=jnp.sum(spikes), overflow=overflow)
+            if record_raster:
+                out["raster"] = spikes.astype(jnp.uint8)
+            if record_v:
+                out["v_mean"] = jnp.mean(vtx_state[:, 0])
         return new_carry, out
 
     def _pending_flush(carry):
         """Apply and drop a trailing '_pending' entry (scan epilogue)."""
         carry = dict(carry)
         pend = carry.pop("_pending")
-        ring, weights = _apply_pending(carry["ring"], carry["weights"], pend)
+        with obs.scope(obs.DELIVER):
+            ring, weights = _apply_pending(
+                carry["ring"], carry["weights"], pend
+            )
         carry["ring"] = ring
         carry["weights"] = weights
         return carry
@@ -787,8 +779,9 @@ class Simulator:
             )
             if self.engine_choice.event else None
         )
-        self.dev = jax.device_put(dataclasses.replace(host, valid=[]))
-        self._noise_ids = jnp.asarray(part.global_ids, jnp.int32)
+        with obs.span(obs.BUILD_PLACE):
+            self.dev = jax.device_put(dataclasses.replace(host, valid=[]))
+            self._noise_ids = jnp.asarray(part.global_ids, jnp.int32)
         self._step_kw = dict(
             registry=net.registry,
             models_present=models,

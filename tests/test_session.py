@@ -61,7 +61,7 @@ def test_session_streaming_raster_is_chunked():
     ras = RasterMonitor()
     res = ses.run(150, monitors=[ras], chunk_size=25)
     assert res.chunks == (25,) * 6
-    assert max(ses.last_run_chunks) == 25 < 150
+    assert max(res.chunks) == 25 < 150
     assert ras.chunks_seen == 6
     assert ras.raster.shape == (150, ses.n)
     assert isinstance(ras.raster, np.ndarray)  # host-side
@@ -169,11 +169,11 @@ def test_session_checkpoint_every_and_corrupt_walkback(tmp_path):
     cfg = SimConfig(align_k=8)
     root = str(tmp_path)
     ses = Session(build(), cfg)
-    ses.run(60, chunk_size=25, checkpoint_every=20, checkpoint_dir=root,
-            max_to_keep=2)
+    res = ses.run(60, chunk_size=25, checkpoint_every=20,
+                  checkpoint_dir=root, max_to_keep=2)
     ses.wait()  # checkpoints are async: drain before inspecting disk
     # chunks align to checkpoint boundaries; retention kept the last two
-    assert ses.last_run_chunks == (20, 20, 20)
+    assert res.chunks == (20, 20, 20)
     assert snapshot_steps(root) == [40, 60]
 
     newest = os.path.join(root, "step_00000060", "part0.npz")
