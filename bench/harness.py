@@ -389,7 +389,8 @@ def _run(args, cell, dev, devices, peak, compiles, tmp, t_start,
             r.ckpt_stalls.extend(one_chunk())
             if time.perf_counter() - t0 >= args.seconds:
                 break
-    r.window_s = time.perf_counter() - t0
+    t_window_end = time.perf_counter()
+    r.window_s = t_window_end - t0
     if args.trace:
         jax.profiler.stop_trace()
     r.steps = (len(rec.chunks) - warm_chunks) * chunk
@@ -488,14 +489,15 @@ def _run(args, cell, dev, devices, peak, compiles, tmp, t_start,
     params = reference.Params.from_config(cfg)
     noise = _noise_fn(seed, net0.n, params.noise_sigma, net0.noise_ids)
     replayed = reference.replay(net0, params, raster[:t_end], noise, control)
-    ref_state, thr_gap = replayed[:2]
+    replay_s = time.perf_counter() - t
+    ref_state = replayed.state
     got = dict(end_state)
     if plastic:
         got["w"] = w_end
-    numbers = reference.gaps(got, ref_state, thr_gap, net0, by_id)
+    numbers = reference.gaps(got, ref_state, replayed.thr_gap, net0, by_id)
     if control:
-        c_state, c_gap = replayed[2]
-        c_numbers = reference.gaps(c_state, ref_state, c_gap, net0, by_id)
+        c_numbers = reference.gaps(replayed.control.state, ref_state,
+                                   replayed.control.thr_gap, net0, by_id)
         c_failed = [k for k, v in c_numbers.items()
                     if k in cell.limits and not v <= cell.limits[k]]
         print(json.dumps(dict(control=control, seed=seed, program=numbers,
@@ -506,8 +508,9 @@ def _run(args, cell, dev, devices, peak, compiles, tmp, t_start,
             checks[name] = (value, cell.limits[name])
         else:
             log(f"[check] {name} {value!r} (not compared in this cell)")
-    log(f"[check] reference replay of {t_end} steps in "
-        f"{time.perf_counter() - t:.3f} s")
+    log(f"[check] reference replay of {t_end} steps, {replayed.events} "
+        f"synaptic events, in {replay_s:.3f} s "
+        f"({replayed.events / max(replay_s, 1e-9):.6g} events/s)")
 
     for d in departures:
         log(f"[check] the program departs from the configuration: {d}")
@@ -534,6 +537,9 @@ def _run(args, cell, dev, devices, peak, compiles, tmp, t_start,
             idle_gaps=[[n, s] for n, s in r.trace.idle_gaps[:10]],
         )
     result["checks"] = {k: {"value": v, "limit": lim} for k, (v, lim) in checks.items()}
+    now = time.perf_counter()
+    log(f"[run] post-window {now - t_window_end:.3f} s, whole run "
+        f"{now - t_start:.3f} s")
     for k, (v, lim) in checks.items():
         log(f"{k} {v!r} limit {lim!r}")
     print(json.dumps(result), flush=True)

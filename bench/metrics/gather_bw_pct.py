@@ -1,6 +1,9 @@
-"""Share of HBM bandwidth the XLA gathers over the ELL panels reach:
+"""Share of HBM bandwidth the delivery gathers over the ELL panels reach:
 the bytes they move (from their own signatures in the trace) over their
-device time, against the chip's peak bandwidth."""
+device time, against the chip's peak bandwidth.  The gathers are XLA's
+(a ``kCustom`` fusion with a panel-sized result reading the panel's
+``s32`` ids) and a delivery kernel's (a ``custom-call`` reading an ``s32``
+operand of a panel's size); ``work.gather_traffic`` finds both."""
 
 
 def read(run):

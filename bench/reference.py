@@ -17,6 +17,11 @@ traces, weights) can be recomputed and compared after any number of steps.
 Where the program's spike disagrees with the reference's threshold test,
 the margin by which it does is the threshold gap.
 
+A step visits only the out-edges of the sources that spike in it, through
+an index of out-edges by source built once per replay, so a replay costs
+in proportion to its synaptic events and its steps times the neurons, not
+to the edges times the steps.
+
 The reference computes in float64.  The lower-precision control that has
 to fail the comparison is the same replay with every result rounded to
 bfloat16, the precision below the configuration's float32.
@@ -96,21 +101,46 @@ class Params:
         )
 
 
-class _Sources:
-    """The out-edges of every source that spikes somewhere in the raster,
-    found once by a pass over the column array (CSR is by target)."""
+def _ranges(lo: np.ndarray, hi: np.ndarray) -> np.ndarray:
+    """The integers of every range ``[lo[i], hi[i])``, concatenated."""
+    cnt = hi - lo
+    return np.repeat(lo - np.cumsum(cnt) + cnt, cnt) + np.arange(cnt.sum())
+
+
+class _OutEdges:
+    """Out-edges by source of every source that spikes somewhere in the
+    raster (the CSR is by target): their edge ids grouped by source, those
+    of source ``s`` at ``edge[ptr[s]:ptr[s + 1]]`` in CSR order, with each
+    one's target row and delay beside it.  Built once, by one sort over
+    the edges of those sources; a step then visits exactly the out-edges of
+    the sources that spike in it, so a replay costs its synaptic events,
+    not edges times steps."""
 
     def __init__(self, net: Network, raster: np.ndarray):
+        if len(net.col) >= 2**31:
+            raise ValueError("the index holds edge ids as int32")
         fired = raster.any(axis=0)
-        idx = np.flatnonzero(fired[net.col])
-        self.idx = idx
-        self.col = net.col[idx]
-        self.row = np.searchsorted(net.row_ptr, idx, side="right") - 1
-        self.delay = net.delay[idx].astype(np.int64)
+        kept = np.flatnonzero(fired[net.col])  # by target, in CSR order
+        row = np.repeat(np.arange(net.n, dtype=np.int32),
+                        np.diff(np.searchsorted(kept, net.row_ptr)))
+        # (source, position in kept) pairs: sorted, they group the edges by
+        # source and keep the CSR order within a source
+        key = net.col[kept].astype(np.int64)
+        key <<= 32
+        key |= np.arange(len(kept))
+        key.sort()
+        self.ptr = np.searchsorted(key, np.arange(net.n + 1, dtype=np.int64) << 32)
+        key &= 0xFFFFFFFF
+        self.row = row[key]
+        self.edge = kept[key].astype(np.int32)
+        del key, kept, row
+        self.delay = net.delay[self.edge].astype(np.int32)
 
-    def of(self, spikes: np.ndarray):
-        """Positions (into this subset) of the edges whose source spiked."""
-        return np.flatnonzero(spikes[self.col])
+    def of(self, spikes: np.ndarray) -> np.ndarray:
+        """Positions (into this index) of the out-edges of the sources
+        that spike."""
+        src = np.flatnonzero(spikes)
+        return _ranges(self.ptr[src], self.ptr[src + 1])
 
 
 class _Replayer:
@@ -126,6 +156,7 @@ class _Replayer:
         self.decay = q(np.exp(-p.dt / p.tau_m))
         self.ref_steps = float(round(p.t_ref / p.dt))
         self.plastic = p.stdp is not None and bool(net.plastic.any())
+        self.events = 0  # synaptic events visited: out-edges of spiking sources
         if self.plastic:
             s = p.stdp
             self.w = q(net.weight.astype(np.float64))
@@ -149,7 +180,7 @@ class _Replayer:
         return np.where(active, v_int, p.v_reset), active
 
     def advance(self, t: int, v_new: np.ndarray, spikes: np.ndarray,
-                src: _Sources) -> None:
+                src: _OutEdges) -> None:
         """Finish step ``t`` with the given (forced) spikes."""
         q, p = self.q, self.p
         slot = t % self.D
@@ -163,24 +194,24 @@ class _Replayer:
             self.tr_plus = q(q(self.tr_plus * self.dec_plus) + sf)
             self.tr_minus = q(q(self.tr_minus * self.dec_minus) + sf)
         # propagate with the weights from before this step's update
-        e = src.of(spikes)
+        at = src.of(spikes)
+        e = src.edge[at]
+        self.events += len(e)
         if len(e):
-            slots = (t + src.delay[e]) % self.D
-            np.add.at(self.ring, (slots, src.row[e]), q(self.w[src.idx[e]]))
+            slots = (t + src.delay[at].astype(np.int64)) % self.D
+            # the ring is C-contiguous, so its flat view adds in place
+            np.add.at(self.ring.reshape(-1), slots * self.net.n + src.row[at],
+                      np.asarray(q(self.w[e]), np.float64))
             if self.q is not _exact:
                 self.ring = q(self.ring)
         if self.plastic:
-            self._stdp(spikes, src.idx[e])
+            self._stdp(spikes, e)
         self.hist[slot] = spikes.astype(np.uint8)
 
     def _stdp(self, spikes: np.ndarray, pre_edges: np.ndarray) -> None:
         q, s, net = self.q, self.p.stdp, self.net
         post_rows = np.flatnonzero(spikes)
-        lo, hi = net.row_ptr[post_rows], net.row_ptr[post_rows + 1]
-        cnt = hi - lo
-        post_edges = (
-            np.repeat(lo - np.cumsum(cnt) + cnt, cnt) + np.arange(cnt.sum())
-        )
+        post_edges = _ranges(net.row_ptr[post_rows], net.row_ptr[post_rows + 1])
         e = np.union1d(pre_edges, post_edges)
         e = e[net.plastic[e]]
         if not len(e):
@@ -216,16 +247,29 @@ def _threshold_gap(spikes, v_new, active, v_thresh) -> float:
     return gap
 
 
+@dataclasses.dataclass
+class Replayed:
+    """What a replay ends with: the state, the threshold gap, and the
+    synaptic events it visited (the out-edges of each step's spiking
+    sources, summed over the steps); with the control, the same of the
+    bfloat16 replay."""
+
+    state: Dict[str, np.ndarray]
+    thr_gap: float
+    events: int
+    control: Optional["Replayed"] = None
+
+
 def replay(net: Network, p: Params, raster: np.ndarray,
            noise: Callable[[int], np.ndarray],
-           control: bool = False):
+           control: bool = False) -> Replayed:
     """Replay steps ``0 .. len(raster) - 1`` forced by ``raster`` (uint8,
     ``(T, n)``).  ``noise(t)`` is the step noise ``(n,)`` in current units.
 
-    Returns ``(state, thr_gap)`` of the float64 reference, and with
-    ``control`` also ``(control_state, control_thr_gap)``: the same replay
-    in bfloat16, whose spike decisions are its own."""
-    src = _Sources(net, raster)
+    Returns the float64 reference's :class:`Replayed`, and with
+    ``control`` also that of the same replay in bfloat16, whose spike
+    decisions are its own."""
+    src = _OutEdges(net, raster)
     ref = _Replayer(net, p, _exact)
     ctl = _Replayer(net, p, _bf16) if control else None
     gap = ctl_gap = 0.0
@@ -242,9 +286,10 @@ def replay(net: Network, p: Params, raster: np.ndarray,
             )
             ctl.advance(t, cv, spikes, src)
         ref.advance(t, v_new, spikes, src)
-    if ctl is None:
-        return ref.state(), gap
-    return ref.state(), gap, (ctl.state(), ctl_gap)
+    out = Replayed(ref.state(), gap, ref.events)
+    if ctl is not None:
+        out.control = Replayed(ctl.state(), ctl_gap, ctl.events)
+    return out
 
 
 def gaps(got: Dict[str, np.ndarray], ref: Dict[str, np.ndarray],

@@ -99,3 +99,46 @@ def test_recorded_tpu_trace():
     # the host ran the monitor span between the calls
     assert s.idle_gaps and s.idle_gaps[0][0] == "bench.monitor"
     assert all(g >= trace.MIN_GAP_NS * 1e-9 for _, g in s.idle_gaps)
+
+
+# a Pallas delivery kernel as the trace names it: packed spike bits, the
+# panel's int32 ids and its weights in, one input current per row out
+PALLAS_DELIVERY = (
+    "%spike_gather_pallas.21 = f32[77176,1]{1,0} custom-call(s32[19,128]{1,0} "
+    "%bits, s32[77176,4736]{1,0:T(8,128)} %cols, f32[77176,4736]{1,0:T(8,128)} "
+    "%w), custom_call_target=\"tpu_custom_call\", operand_layout_constraints="
+    "{s32[19,128]{1,0}, s32[77176,4736]{1,0}, f32[77176,4736]{1,0}}"
+)
+XLA_GATHER = (
+    "%fusion.45 = f32[365505536]{0} fusion(f32[77169]{0} %v, "
+    "s32[365505536]{0} %ids), kind=kCustom, calls=%fused_computation.45"
+)
+
+
+def test_gather_traffic_counts_a_delivery_custom_call():
+    """A custom-call reading a panel-sized int32 operand is a delivery
+    gather: its bytes are its result's and every operand's, the layout
+    constraints after them not counted again."""
+    panel = 77176 * 4736
+    secs, nbytes = work.gather_traffic({PALLAS_DELIVERY: 0.5},
+                                       {PALLAS_DELIVERY: 3}, [77176 * 1536, panel])
+    assert secs == 0.5
+    assert nbytes == 3 * (77176 * 4 + 19 * 128 * 4 + panel * 4 + panel * 4)
+    # another panel size, or a custom-call with no panel-sized ids: none
+    assert work.gather_traffic({PALLAS_DELIVERY: 0.5}, {PALLAS_DELIVERY: 3},
+                               [77176 * 1536]) == (0.0, 0.0)
+    other = PALLAS_DELIVERY.replace("s32[77176,4736]{1,0:T", "f32[77176,4736]{1,0:T")
+    assert work.gather_traffic({other: 0.5}, {other: 3}, [panel]) == (0.0, 0.0)
+
+
+def test_gather_traffic_adds_both_kinds():
+    """XLA's gather fusion reads as before beside a delivery custom-call."""
+    n = 365505536
+    ops = {XLA_GATHER: 2.0, PALLAS_DELIVERY: 0.5}
+    counts = {XLA_GATHER: 5, PALLAS_DELIVERY: 3}
+    secs, nbytes = work.gather_traffic(ops, counts, [n, 77176 * 4736])
+    alone = work.gather_traffic({XLA_GATHER: 2.0}, {XLA_GATHER: 5}, [n])
+    assert alone == (2.0, 5 * (n * 4 + 77169 * 4 + n * 4))
+    assert secs == 2.5
+    assert nbytes == alone[1] + work.gather_traffic(
+        {PALLAS_DELIVERY: 0.5}, {PALLAS_DELIVERY: 3}, [77176 * 4736])[1]

@@ -4,11 +4,15 @@ computes them the same way.
 
 Two counts, for two metrics:
 
-* ``gather_traffic`` — what the XLA gathers over the ELL panels move,
-  for their share of HBM bandwidth: each gather's operands (the int32
-  ids and the vector gathered from) and its result, read from the op's
-  own HLO signature in the trace.  (The weights are read by a separate
-  multiply-reduce op, not by the gather.)
+* ``gather_traffic`` — what the delivery gathers over the ELL panels
+  move, for their share of HBM bandwidth, read from each op's own HLO
+  signature in the trace (its operands and its result).  Two kinds of op
+  count: XLA's gather, a ``kCustom`` fusion with a panel-sized result that
+  reads the panel's int32 ids (its operands are the ids and the vector
+  gathered from; the weights are read by a separate multiply-reduce op);
+  and a delivery kernel of the program's own, a ``custom-call`` that
+  reads an int32 operand of a panel's size (the ids; its other operands,
+  such as the weights, and its result count too).
 
 * ``least_step_work`` — what any implementation of one step has to move
   and compute, whatever layout it uses:
@@ -47,23 +51,37 @@ _DTYPE_BYTES = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2, "u8": 1,
 _SHAPE = re.compile(r"\b(" + "|".join(_DTYPE_BYTES) + r")\[([\d,]*)\]")
 
 
+def _signature(text: str):
+    """(dtype, elements) of each array shape in a piece of HLO text, in
+    order: an op's result comes before its operands."""
+    return [(t, int(np.prod([int(x) for x in dims.split(",") if x])))
+            for t, dims in _SHAPE.findall(text)]
+
+
 def gather_traffic(op_seconds: Dict[str, float], op_counts: Dict[str, int],
                    panel_sizes: Iterable[int]) -> Tuple[float, float]:
-    """(device seconds, bytes moved) of the XLA gathers over the ELL
-    panels, from a trace's ops (keyed by their HLO text).  A gather is a
-    ``kCustom`` fusion whose result has as many elements as one of the
-    panels and which reads an ``s32`` index operand of that size."""
+    """(device seconds, bytes moved) of the delivery gathers over the ELL
+    panels, from a trace's ops (keyed by their HLO text).  A gather is
+    either a ``kCustom`` fusion whose result has as many elements as one
+    of the panels and which reads an ``s32`` index operand of that size,
+    or a ``custom-call`` that reads an ``s32`` operand of a panel's size.
+    Its bytes are those of its result and operands."""
     sizes = set(panel_sizes)
     secs = moved = 0.0
     for name, s in op_seconds.items():
-        if "kind=kCustom" not in name or " fusion(" not in name:
-            continue
-        elems = [
-            (t, int(np.prod([int(x) for x in dims.split(",") if x])))
-            for t, dims in _SHAPE.findall(name.split(", kind=")[0])
-        ]
-        _, out_n = elems[0]
-        if out_n not in sizes or ("s32", out_n) not in elems[1:]:
+        if "kind=kCustom" in name and " fusion(" in name:
+            elems = _signature(name.split(", kind=")[0])
+            _, out_n = elems[0]
+            if out_n not in sizes or ("s32", out_n) not in elems[1:]:
+                continue
+        elif " custom-call(" in name:
+            result, operands = name.split(" custom-call(", 1)
+            # the attributes after the operands restate their shapes
+            operands = _signature(operands.split(", custom_call_target=")[0])
+            if not any(("s32", n) in operands for n in sizes):
+                continue
+            elems = _signature(result) + operands
+        else:
             continue
         secs += s
         moved += op_counts[name] * sum(_DTYPE_BYTES[t] * n for t, n in elems)
