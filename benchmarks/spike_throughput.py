@@ -71,8 +71,9 @@ per-mode us/step, synaptic events/s, engine and backend, plus
 fused-vs-unfused speedups.
 
 On CPU the Pallas engines run in interpret mode, so the fused-vs-unfused
-numbers are an emulation proxy.  On a TPU the Pallas synapse kernels do
-not compile (``kernels.dispatch.PALLAS_GATHER_LIMIT``), and the
+numbers are an emulation proxy.  On a TPU the Pallas synapse kernels
+other than the spike delivery do not compile
+(``kernels.dispatch.PALLAS_GATHER_LIMIT``), and the
 ``dist``/``plastic``/``overlap``/``ingest`` modes start child processes
 that would need the chip this process holds: there ``--mode`` accepts
 only ``ref``, ``ckpt``, ``serialization`` and ``recovery``, which run

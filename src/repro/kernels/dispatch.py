@@ -13,8 +13,10 @@ Backends:
 Resolution order: explicit ``backend=`` argument > ``REPRO_BACKEND``
 environment variable > platform default (``pallas`` on TPU, otherwise
 ``pallas_interpret`` for direct kernel calls; the simulators default to
-``ref`` on every platform — the synapse kernels' in-kernel gather does
-not compile for TPU, see ``PALLAS_GATHER_LIMIT``).
+``ref`` on every platform, and on a TPU their default step delivers spikes
+through the compiled ``spike_gather`` kernel over packed spike bits —
+the one synapse kernel the TPU compiler accepts, see
+``PALLAS_GATHER_LIMIT`` and ``resolve_delivery_backend``).
 
 Separately from the *kernel* backend, ``select_step_engine`` decides the
 *step engine*:
@@ -136,29 +138,36 @@ def resolve_backend(
     return default if default is not None else _platform_default()
 
 
-# What the TPU compiler refuses in every synapse kernel (spike_gather,
-# stdp_update, all fused_* and event_* kernels): each gathers activity by
-# presynaptic id from a VMEM-resident vector, ``jnp.take(act, cols)``.
-# Compiled for a TPU v5e with jax/jaxlib 0.9.0, Mosaic lowers only 2-D
-# gathers whose indices have the source's shape, so these kernels raise
-# ``NotImplementedError: Only 2D gather is supported``; they run only in
-# interpret mode.  The compile tests (tests/test_tpu_compile.py) pin this.
+# What the TPU compiler refuses in the synapse kernels that gather
+# activity by presynaptic id from a VMEM-resident vector,
+# ``jnp.take(act, cols)``: stdp_update and every fused_* and event_*
+# kernel.  Compiled for a TPU v5e with jax/jaxlib 0.9.0, Mosaic lowers
+# only 2-D gathers whose indices have the source's shape, so these raise
+# ``NotImplementedError: Only 2D gather is supported`` and run only in
+# interpret mode.  The spike delivery kernel (``spike_gather``) is written
+# to that rule: it reads the spikes packed into (S, 128) words and gathers
+# along the lanes of one words row at a time, so it compiles.  The compile
+# tests (tests/test_tpu_compile.py) pin both.
 PALLAS_GATHER_LIMIT = (
     "the TPU compiler (Mosaic, jax 0.9) lowers no in-kernel 1-D gather: "
     "jnp.take(activity, cols) from a VMEM-resident vector raises "
-    "'NotImplementedError: Only 2D gather is supported', and every "
-    "synapse kernel gathers that way"
+    "'NotImplementedError: Only 2D gather is supported', and stdp_update "
+    "and every fused and event kernel gather that way; only the spike "
+    "delivery kernel (spike_gather, a lane gather over packed spike bits) "
+    "compiles"
 )
 
 # Why the simulators default to 'ref' on every platform (shown by
-# Session.describe()): the XLA-compiled step composes the jnp oracles, the
-# only synapse path the TPU compiler accepts today (PALLAS_GATHER_LIMIT);
-# on CPU it is also the fast path, XLA fusing the oracles.
+# Session.describe()): the XLA-compiled step composes the jnp oracles,
+# apart from the one synapse kernel the TPU compiler accepts
+# (PALLAS_GATHER_LIMIT), which delivers the spikes of the default step on
+# a TPU; on CPU XLA's fusion of the oracles is the fast path.
 SIM_BACKEND_REASON = (
     "simulators default to 'ref' (the XLA-compiled step) on every "
-    "platform: on TPU the Pallas synapse kernels do not compile ("
-    + PALLAS_GATHER_LIMIT + "); on CPU XLA's fusion of the oracles is "
-    "the fast path"
+    "platform; on TPU that step delivers spikes through the compiled "
+    "Pallas kernel over packed spike bits, and the other synapse kernels "
+    "do not compile (" + PALLAS_GATHER_LIMIT + "); on CPU XLA's fusion "
+    "of the oracles is the fast path"
 )
 
 
@@ -170,18 +179,40 @@ def resolve_sim_backend(backend: Optional[str] = None) -> str:
     return resolve_backend(backend, default="ref")
 
 
-def require_compilable(backend: str, choice: "StepEngineChoice") -> None:
+def resolve_delivery_backend(backend: Optional[str] = None) -> str:
+    """Backend of the unfused step's spike delivery (``spike_gather``):
+    the simulators' backend, except that the default step on a TPU (no
+    explicit backend, no ``REPRO_BACKEND``) delivers through the compiled
+    Pallas kernel.  An explicit ``'ref'`` keeps the all-XLA oracle step
+    on every platform."""
+    if (backend is None and not os.environ.get("REPRO_BACKEND")
+            and _platform_default() == "pallas"):
+        return "pallas"
+    return resolve_sim_backend(backend)
+
+
+def require_compilable(
+    backend: str, choice: "StepEngineChoice", plastic: bool
+) -> None:
     """Refuse, at engine construction, a compiled-Pallas step the TPU
-    compiler cannot build: every step engine propagates spikes through a
-    synapse kernel with an in-kernel gather (``PALLAS_GATHER_LIMIT``).
-    Raised, never swapped for another backend."""
-    if backend == "pallas":
-        raise ValueError(
-            f"backend='pallas' cannot run the {choice.engine!r} step "
-            f"engine: {PALLAS_GATHER_LIMIT}; use backend='ref' (the "
-            "default, XLA-compiled) or 'pallas_interpret' (CPU "
-            "validation of the kernels)"
-        )
+    compiler cannot build (``PALLAS_GATHER_LIMIT``): every fused and
+    event engine, and the unfused engine's ``stdp_update`` pass.  With
+    ``backend='pallas'`` only the unfused step of a partition without
+    STDP compiles.  Raised, never swapped for another backend."""
+    if backend != "pallas":
+        return
+    if choice.fused:
+        blocked = f"the {choice.engine!r} step engine"
+    elif plastic:
+        blocked = "the unfused step's stdp_update pass"
+    else:
+        return
+    raise ValueError(
+        f"backend='pallas' cannot run {blocked}: {PALLAS_GATHER_LIMIT}; "
+        "use the default backend (the XLA-compiled step, spikes "
+        "delivered by the Pallas kernel on a TPU), fused=False without "
+        "STDP, or 'pallas_interpret' (CPU validation of the kernels)"
+    )
 
 
 def lookup(op: str, backend: Optional[str] = None) -> Callable:

@@ -32,7 +32,7 @@ from .fused_step import (
     fused_pre_exchange_pallas,
 )
 from .lif_step import lif_step_pallas
-from .spike_gather import spike_gather_pallas
+from .spike_gather import spike_gather_bits_pallas, spike_gather_pallas
 from .stdp_update import stdp_update_pallas
 
 
@@ -86,10 +86,30 @@ def _spike_gather_ref(activity, cols, weights, **kw):
 _register_pallas("spike_gather")(spike_gather_pallas)
 
 
+@register("spike_gather_bits", "ref")
+def _spike_gather_bits_ref(words, cols, weights, *, fired=False, **kw):
+    return ref.spike_gather_bits_ref(words, cols, weights, fired)
+
+
+_register_pallas("spike_gather_bits")(spike_gather_bits_pallas)
+
+
 def spike_gather(
     activity, cols, weights, *, backend: Optional[str] = None, **kw
 ):
+    """Spike delivery over one ELL panel: ``currents[r] = sum_k
+    weights[r,k] * activity[cols[r,k]]`` for 0/1 ``activity``, in f32."""
     return lookup("spike_gather", backend)(activity, cols, weights, **kw)
+
+
+def spike_gather_bits(
+    words, cols, weights, *, backend: Optional[str] = None, **kw
+):
+    """``spike_gather`` from activity packed once by
+    ``kernels.spike_gather.pack_spikes``, so a step packs its spikes once
+    for every panel.  ``fired=True`` also returns each slot's presynaptic
+    spike, (R, K) f32 0/1, for ``stdp_update(..., pre_fired=...)``."""
+    return lookup("spike_gather_bits", backend)(words, cols, weights, **kw)
 
 
 # -- lif_step -------------------------------------------------------------
@@ -119,11 +139,11 @@ def _stdp_args(params):
 @register("stdp_update", "ref")
 def _stdp_update_ref(
     weights, valid, cols, pre_trace, pre_spike, post_trace, post_spike,
-    *, params, **kw
+    *, params, pre_fired=None, **kw
 ):
     return ref.stdp_update_ref(
         weights, valid, cols, pre_trace, pre_spike, post_trace, post_spike,
-        **_stdp_args(params),
+        **_stdp_args(params), pre_fired=pre_fired,
     )
 
 

@@ -5,7 +5,7 @@ match (tests sweep shapes/dtypes and assert_allclose against these).
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -25,6 +25,21 @@ def spike_gather_ref(
     """
     vals = jnp.take(activity, cols, axis=0).astype(jnp.float32)
     return jnp.sum(weights.astype(jnp.float32) * vals, axis=-1)
+
+
+def spike_gather_bits_ref(
+    words: jnp.ndarray,  # (S, 128) int32: the activity, from pack_spikes
+    cols: jnp.ndarray,  # (R, K) int32 global source ids (0 on padding)
+    weights: jnp.ndarray,  # (R, K) weights (0 on padding)
+    fired: bool = False,
+):
+    """``spike_gather_ref`` with the activity packed into bits: neuron i
+    fired iff bit ``i & 31`` of word ``i >> 5`` is set.  With ``fired``
+    also returns each slot's presynaptic spike, (R, K) f32 0/1."""
+    word = jnp.take(words.reshape(-1), cols >> 5, axis=0)
+    hit = ((word >> (cols & 31)) & 1) != 0
+    cur = jnp.sum(jnp.where(hit, weights.astype(jnp.float32), 0.0), axis=-1)
+    return (cur, hit.astype(jnp.float32)) if fired else cur
 
 
 def lif_step_ref(
@@ -100,6 +115,7 @@ def stdp_update_ref(
     a_minus: float,
     w_min: float,
     w_max: float,
+    pre_fired: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Trace-based pair STDP (all-to-all interaction):
 
@@ -108,10 +124,14 @@ def stdp_update_ref(
 
     applied simultaneously per step; weights clipped to [w_min, w_max].
     Slots with ``valid == 0`` (padding *or* non-plastic synapses) keep their
-    original weight unchanged.
+    original weight unchanged.  ``pre_fired``, the (R, K) presynaptic spike
+    of each slot as the delivery kernel read it, replaces the gather of
+    ``pre_spike``.
     """
     pre_t = jnp.take(pre_trace, cols, axis=0)
-    pre_s = jnp.take(pre_spike, cols, axis=0)
+    pre_s = (
+        jnp.take(pre_spike, cols, axis=0) if pre_fired is None else pre_fired
+    )
     dw = (
         a_plus * pre_t * post_spike[:, None]
         - a_minus * post_trace[:, None] * pre_s

@@ -58,14 +58,15 @@ from ..launch.mesh import make_snn_mesh
 from ..core.dcsr import DCSRNetwork
 from ..core.ell import build_delay_ell
 from ..kernels.dispatch import (
-    event_id_cap, require_compilable, resolve_sim_backend,
-    select_step_engine,
+    event_id_cap, require_compilable, resolve_delivery_backend,
+    resolve_sim_backend, select_step_engine,
 )
 from ..kernels.event_step import (
     EventPlan, build_touch_masks, event_block_geometry,
 )
 from .simulator import (
     SimConfig,
+    delivery_path,
     make_core_step,
     partition_device_data,
     _models_present,
@@ -240,6 +241,7 @@ class DistSimulator:
             mesh = make_snn_mesh(k)
         self.mesh = mesh
         self.backend = resolve_sim_backend(cfg.backend)
+        self.deliver_backend = resolve_delivery_backend(cfg.backend)
         self.stdp_params = (
             dict(net.registry.spec("syn_stdp").params)
             if s.any_plastic else None
@@ -296,7 +298,12 @@ class DistSimulator:
             gather="dense" if cfg.gather == "auto" else cfg.gather,
             **sel_kw,
         )
-        require_compilable(self.backend, self.engine_choice)
+        require_compilable(
+            self.backend, self.engine_choice, sel_kw["any_plastic"]
+        )
+        self.delivery = delivery_path(
+            self.engine_choice, self.deliver_backend, self.n_global
+        )
         self.event_capable = _probe_event_capable(**sel_kw)
         # the non-plastic overlap engines gather build-time ownership
         # sub-panels; plastic panels stay whole (weights are state)
@@ -417,6 +424,7 @@ class DistSimulator:
             n_global=self.n_global,
             dev=dev_template,
             backend=self.backend,
+            deliver_backend=self.deliver_backend,
             stdp_params=self.stdp_params,
             exchange=exchange,
             noise_ids=noise_ids,

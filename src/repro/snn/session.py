@@ -19,10 +19,12 @@ Engine selection (``engine="auto"``):
     anywhere.
 
 The kernel backend defaults to ``ref`` — the XLA-compiled step — on every
-platform, TPU included: the Pallas synapse kernels do not compile for a TPU
-(``kernels.dispatch.PALLAS_GATHER_LIMIT``), and an explicit
-``SimConfig(backend="pallas")`` raises at construction.  ``describe()``
-names the engine and backend that run, and why.
+platform, TPU included; on a TPU that step delivers its spikes through the
+compiled Pallas kernel over packed spike bits, the one synapse kernel the
+TPU compiler accepts (``kernels.dispatch.PALLAS_GATHER_LIMIT``).  An
+explicit ``SimConfig(backend="pallas")`` raises at construction unless
+the step is the unfused one of a network without STDP.  ``describe()``
+names the engine, backend and delivery kernel that run, and why.
 
 Both engines share one output contract (see :mod:`repro.snn.monitors`):
 ``spike_count`` ``(steps,)`` int32 summed over partitions, ``raster``
@@ -500,6 +502,7 @@ class Session:
             if self.cfg.backend or os.environ.get("REPRO_BACKEND")
             else SIM_BACKEND_REASON
         )
+        d["delivery"] = dict(self._current_engine.sim.delivery)
         if isinstance(self._current_engine, _SingleEngine):
             d["ell_fill"] = self._current_engine.sim.ell.fill_factor
         else:

@@ -8,7 +8,9 @@ One step (documented order — the serialization contract depends on it):
   4. exchange: act/pre-trace become global vectors (identity for k = 1,
      all-gather in the distributed wrapper).
   5. propagate with *pre-update* weights: per delay bucket b,
-     ``ring[(t + d_b) % D] += spike_gather(act, cols_b, w_b)``.
+     ``ring[(t + d_b) % D] += spike_gather(act, cols_b, w_b)`` (on a TPU
+     the spikes are packed into bits once and every bucket's panel is
+     delivered by the Pallas kernel over them).
   6. STDP: w' from the fused kernel (plastic slots only).
   7. history: ``hist[t % D] = s_t`` (for in-flight event serialization).
 
@@ -33,9 +35,10 @@ from ..core.state import EDGE_WEIGHT
 from ..kernels import ops
 from ..kernels.dispatch import (
     BACKENDS, StepEngineChoice, event_id_cap, require_compilable,
-    resolve_sim_backend, select_step_engine,
+    resolve_delivery_backend, resolve_sim_backend, select_step_engine,
 )
 from ..kernels.event_step import EventPlan
+from ..kernels.spike_gather import WORD_BITS, pack_spikes
 from .neurons import (
     LIF_BIAS, LIF_PARAM_KEYS, LIF_REF, LIF_V, make_neuron_step,
 )
@@ -237,6 +240,20 @@ def _probe_event_capable(**sel_kw) -> bool:
         return False
 
 
+def delivery_path(
+    choice: StepEngineChoice, deliver_backend: str, n_global: int
+) -> Dict:
+    """What delivers the step's spikes (``Session.describe()['delivery']``):
+    a fused engine's own kernel, XLA's element-wise gather (``xla_take``),
+    or the Pallas kernel over ``words`` packed spike words
+    (``pallas_bits``)."""
+    if choice.fused:
+        return {"kernel": choice.engine}
+    if deliver_backend == "ref":
+        return {"kernel": "xla_take"}
+    return {"kernel": "pallas_bits", "words": -(-n_global // WORD_BITS)}
+
+
 def make_core_step(
     *,
     registry,
@@ -261,8 +278,15 @@ def make_core_step(
     engine_choice: Optional[StepEngineChoice] = None,
     overlap: str = "off",
     overlap_ctx: Optional[Dict[str, Callable]] = None,
+    deliver_backend: Optional[str] = None,
 ) -> Callable:
     """The shared per-partition step; ``exchange`` injects the collective.
+
+    ``deliver_backend`` (default: ``backend``) runs the unfused engine's
+    spike delivery: ``ref`` gathers the activity element-wise in XLA, a
+    Pallas backend packs the exchanged 0/1 activity into bits once per
+    step and delivers every bucket's panel through the packed-bit kernel
+    (``kernels.dispatch.resolve_delivery_backend``).
 
     ``exchange(spikes, tr_plus)`` returns ``(act, pre_trace, overflow)``
     where ``overflow`` is the number of local spikes the collective
@@ -329,6 +353,12 @@ def make_core_step(
             "partition-geometry closures"
         )
     overlap_on = choice.overlap in ("local", "double_buffer")
+    if deliver_backend is None:
+        deliver_backend = backend
+    deliver_bits = deliver_backend != "ref"
+    # the XLA STDP pass reads each slot's presynaptic spike from the
+    # delivery kernel instead of gathering it a second time
+    fired_to_stdp = deliver_bits and any_plastic and backend == "ref"
     if choice.event and event_plan is None:
         event_plan = EventPlan.build(
             dev.cols, dev.valid, n_global, D,
@@ -642,11 +672,25 @@ def make_core_step(
 
             weights = weights0
             new_weights = []
+            if deliver_bits:
+                # one packing of the exchanged activity serves every bucket
+                with obs.scope(obs.DELIVER):
+                    words = pack_spikes(act)
             for i, d in enumerate(dev.delays):
                 with obs.scope(obs.DELIVER), obs.delay_scope(d):
-                    cur = ops.spike_gather(
-                        act, dev.cols[i], weights[i], backend=backend
-                    )
+                    pre_fired = None
+                    if deliver_bits:
+                        cur = ops.spike_gather_bits(
+                            words, dev.cols[i], weights[i],
+                            backend=deliver_backend, fired=fired_to_stdp,
+                        )
+                        if fired_to_stdp:
+                            cur, pre_fired = cur
+                    else:
+                        cur = ops.spike_gather(
+                            act, dev.cols[i], weights[i],
+                            backend=deliver_backend,
+                        )
                     if dev.identity_rows[i]:
                         cur_rows = cur[:n_p]
                     else:
@@ -666,11 +710,14 @@ def make_core_step(
                     if not dev.identity_rows[i]:
                         post_t = jnp.take(tr_minus, dev.row_maps[i], axis=0)
                         post_s = jnp.take(spikes, dev.row_maps[i], axis=0)
+                    stdp_kw = {} if pre_fired is None else {
+                        "pre_fired": pre_fired
+                    }
                     new_weights.append(
                         ops.stdp_update(
                             weights[i], dev.plastic[i], dev.cols[i],
                             pre_trace, act, post_t, post_s,
-                            params=stdp_params, backend=backend,
+                            params=stdp_params, backend=backend, **stdp_kw,
                         )
                     )
             new_weights = tuple(new_weights)
@@ -741,6 +788,7 @@ class Simulator:
         self.d_ring = max(self.ell.max_delay, 1)
         host = partition_device_data(part, net, self.ell)
         self.backend = resolve_sim_backend(cfg.backend)
+        self.deliver_backend = resolve_delivery_backend(cfg.backend)
         stdp = (
             dict(net.registry.spec("syn_stdp").params)
             if host.any_plastic
@@ -767,7 +815,12 @@ class Simulator:
             overlap="off" if cfg.overlap == "auto" else cfg.overlap,
             **sel_kw,
         )
-        require_compilable(self.backend, self.engine_choice)
+        require_compilable(
+            self.backend, self.engine_choice, sel_kw["any_plastic"]
+        )
+        self.delivery = delivery_path(
+            self.engine_choice, self.deliver_backend, net.n
+        )
         self.event_capable = _probe_event_capable(**sel_kw)
         # the event schedule is built from the host panels; only what the
         # step reads goes to the device (validity masks stay on the host)
@@ -796,6 +849,7 @@ class Simulator:
             record_raster=cfg.record_raster,
             record_v=cfg.record_v,
             engine_choice=self.engine_choice,
+            deliver_backend=self.deliver_backend,
         )
         # the step over the resident constants, for program analysis
         # (repro.analysis.contracts traces it); run() rebuilds it over

@@ -330,16 +330,22 @@ def test_sim_backend_is_ref_on_a_tpu_platform(monkeypatch):
 @pytest.mark.parametrize("fused", [None, False])
 def test_compiled_pallas_backend_raises_at_session(fused):
     """An explicit compiled-Pallas backend is refused at construction,
-    naming the compiler's limit — for the fused engines and for the
-    unfused one (whose spike_gather kernel gathers the same way)."""
-    from repro.builder import microcircuit_rules
+    naming the compiler's limit, wherever the step holds a kernel the TPU
+    compiler refuses: the fused engines, and the unfused engine's
+    stdp_update pass.  Without STDP the unfused step compiles (its
+    delivery kernel reads packed spike bits) and is built."""
+    from repro.builder import balanced_ei_rules, microcircuit_rules
     from repro.snn import Session, SimConfig
 
+    cfg = SimConfig(backend="pallas", fused=fused)
     with pytest.raises(ValueError, match="Only 2D gather is supported"):
-        Session(
-            microcircuit_rules(scale=0.01),
-            SimConfig(backend="pallas", fused=fused),
-        )
+        Session(balanced_ei_rules(n=200, stdp=True), cfg)
+    if fused is None:
+        with pytest.raises(ValueError, match="Only 2D gather is supported"):
+            Session(microcircuit_rules(scale=0.01), cfg)
+    else:
+        ses = Session(microcircuit_rules(scale=0.01), cfg)
+        assert ses.describe()["delivery"]["kernel"] == "pallas_bits"
 
 
 ELIGIBLE = dict(

@@ -18,14 +18,20 @@ from jax.sharding import SingleDeviceSharding
 from repro.builder.rules import microcircuit_rules
 from repro.core.state import default_registry
 from repro.kernels import ops
-from repro.kernels.dispatch import resolve_sim_backend, select_step_engine
+from repro.kernels import dispatch
+from repro.kernels.dispatch import (
+    resolve_delivery_backend, resolve_sim_backend, select_step_engine,
+)
 from repro.kernels.keystream import _keystream_call
+from repro.kernels.spike_gather import pack_spikes
 from repro.snn.neurons import LIF_PARAM_KEYS, registry_with_bias
 from repro.snn.simulator import PartitionDeviceData, make_core_step
 
 HBM_BYTES = 16 * 10**9  # one v5e chip
 N = 77169  # scale-1.0 microcircuit
+N_BRUNEL = 12500  # Brunel 2000 at the published size: 128-wide panels
 GATHER_REFUSED = "Only 2D gather is supported"
+DELIVERY_CALL = 'custom_call_target="tpu_custom_call"'
 
 
 @pytest.fixture(scope="module")
@@ -56,6 +62,18 @@ def no_compile_cache():
     compilation_cache.reset_cache()
     yield
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Steer the platform default to a TPU's, so the simulators' default
+    step is the one a TPU runs (its delivery kernel compiled, not
+    interpreted); the compile itself still targets the described chip."""
+    monkeypatch.delenv("REPRO_BACKEND", raising=False)
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
+    dispatch._platform_default.cache_clear()
+    yield
+    dispatch._platform_default.cache_clear()
 
 
 @pytest.fixture(scope="module")
@@ -140,10 +158,6 @@ def _gather_cases(shape):
     ring = [shape((4 * n_p,)), shape((d, n_p)), shape((d,)),
             shape((len(ks), d))]
     return {
-        "spike_gather": (
-            lambda a, c, w: ops.spike_gather(a, c, w, backend="pallas"),
-            [shape((N,)), cols[-1], ws[-1]],
-        ),
         "stdp_update": (
             lambda w, v, c, pt, ps, qt, qs: ops.stdp_update(
                 w, v, c, pt, ps, qt, qs, params=stdp, backend="pallas"),
@@ -165,16 +179,49 @@ def _gather_cases(shape):
 
 
 @pytest.mark.parametrize(
-    "kernel",
-    ["spike_gather", "stdp_update", "fused_step", "fused_post_exchange"],
+    "kernel", ["stdp_update", "fused_step", "fused_post_exchange"],
 )
 def test_synapse_kernels_are_refused(shape, kernel):
-    """Pins the reason the TPU path is the XLA step: Mosaic refuses the
-    in-kernel gather of every synapse kernel.  When this starts to
-    compile, the Pallas engines can come back to the chip."""
+    """Pins the reason the TPU step is XLA's but for its delivery: Mosaic
+    refuses the in-kernel 1-D gather of every other synapse kernel.  When
+    this starts to compile, the Pallas engines can come back to the
+    chip."""
     fn, args = _gather_cases(shape)[kernel]
     with pytest.raises(NotImplementedError, match=GATHER_REFUSED):
         _compile(fn, *args)
+
+
+@pytest.mark.parametrize(
+    "width", ["mc_first", "mc_last", "brunel", "brunel_stdp"]
+)
+def test_delivery_kernel_compiles(shape, width):
+    """The spike delivery kernel over packed bits compiles for the v5e at
+    the scale-1.0 microcircuit's panel widths and at Brunel's 128 (also
+    writing each slot's presynaptic spike for STDP), as one named
+    custom-call reading the panel's own int32 ids."""
+    R, widths = _scale1_ell()
+    n, K = {
+        "mc_first": (N, min(widths.values())),
+        "mc_last": (N, max(widths.values())),
+        "brunel": (N_BRUNEL, 128),
+        "brunel_stdp": (N_BRUNEL, 128),
+    }[width]
+    if n == N_BRUNEL:
+        R = -(-n // 8) * 8
+    fired = width == "brunel_stdp"
+
+    def deliver(a, i, w):
+        words = pack_spikes(a)
+        return ops.spike_gather_bits(words, i, w, backend="pallas",
+                                     fired=fired)
+
+    c = _compile(deliver, shape((n,)), shape((R, K), jnp.int32),
+                 shape((R, K)))
+    calls = [line for line in c.as_text().splitlines()
+             if DELIVERY_CALL in line]
+    assert len(calls) == 1 and "snn_deliver_bits" in calls[0], calls
+    assert f"s32[{R},{K}]" in calls[0]
+    assert (f"f32[{R},{K}]" in calls[0].split("custom-call(")[0]) == fired
 
 
 def test_builder_keystream_kernel_is_refused(shape):
@@ -187,10 +234,13 @@ def test_builder_keystream_kernel_is_refused(shape):
         )
 
 
-def test_default_tpu_step_fits_one_chip(shape):
-    """The engine a TPU runs by default (the XLA 'ref' step) compiles as a
-    scan step over the scale-1.0 microcircuit's panels, inside 16 GB."""
+def test_default_tpu_step_fits_one_chip(shape, on_tpu):
+    """The engine a TPU runs by default (the XLA 'ref' step, its spikes
+    delivered by the packed-bit kernel, one custom-call per delay bucket)
+    compiles as a scan step over the scale-1.0 microcircuit's panels,
+    inside 16 GB."""
     backend = resolve_sim_backend()
+    deliver = resolve_delivery_backend()
     R, widths = _scale1_ell()
     delays = tuple(widths)
     d_ring = max(delays)
@@ -200,7 +250,7 @@ def test_default_tpu_step_fits_one_chip(shape):
         identity_exchange=True, identity_rows=True,
         n_delay_buckets=len(delays), n_p=N, n_global=N,
     )
-    assert (backend, choice.engine) == ("ref", "unfused")
+    assert (backend, deliver, choice.engine) == ("ref", "pallas", "unfused")
     panels_i = [shape((R, k), jnp.int32) for k in widths.values()]
     panels_f = [shape((R, k)) for k in widths.values()]
     dev = PartitionDeviceData(
@@ -226,17 +276,19 @@ def test_default_tpu_step_fits_one_chip(shape):
             stdp_params=None,
             exchange=lambda s, tr: (s, tr, jnp.zeros((), jnp.int32)),
             noise_ids=noise_ids, record_raster=True, engine_choice=choice,
+            deliver_backend=deliver,
         )
         return jax.lax.scan(step, state, None, length=1)
 
     c = _compile(run, dev, shape((N,), jnp.int32), state)
-    assert "tpu_custom_call" not in c.as_text()
+    assert c.as_text().count(DELIVERY_CALL) == len(delays)
     assert _device_bytes(c) < HBM_BYTES, c.memory_analysis()
 
 
-def test_spmd_step_compiles_for_four_chips(topo):
+def test_spmd_step_compiles_for_four_chips(topo, on_tpu):
     """The k=4 SPMD engine's chunk program, partitioned over a 2x2 mesh of
-    described chips, with its spike exchange as a collective."""
+    described chips, with its spike exchange as a collective and its
+    delivery kernel once per delay bucket."""
     from jax.sharding import AxisType, Mesh
 
     from repro.builder.procedural import build_network
@@ -248,6 +300,7 @@ def test_spmd_step_compiles_for_four_chips(topo):
     net = build_network(microcircuit_rules(scale=0.02), k=4, uniform=True)
     sim = DistSimulator(net, SimConfig(), mesh=mesh)
     assert (sim.backend, sim.engine_choice.engine) == ("ref", "unfused")
+    assert sim.delivery["kernel"] == "pallas_bits"
     text = sim.lower(10).compile().as_text()
     assert "all-reduce" in text or "all-gather" in text
-    assert "tpu_custom_call" not in text
+    assert text.count(DELIVERY_CALL) == len(sim.stacked.delays)
