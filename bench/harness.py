@@ -136,26 +136,6 @@ def build_spec(config: dict):
     return builder(**config["builder_args"], seed=int(config["network_seed"]))
 
 
-def _departures(net, config: dict) -> List[str]:
-    """Where the built network departs from the parameters the
-    configuration states (neuron and synapse models, dt, noise); its sizes
-    and connectivity are ``structure``'s to check."""
-    out = []
-    lif = net.registry.spec("lif").params
-    for k, v in config["neuron"].items():
-        if k != "model" and float(lif[k]) != float(v):
-            out.append(f"lif {k}={lif[k]}, configuration states {v}")
-    if config.get("stdp"):
-        st = net.registry.spec("syn_stdp").params
-        for k, v in config["stdp"].items():
-            if float(st[k]) != float(v):
-                out.append(f"stdp {k}={st[k]}, configuration states {v}")
-    for key, meta in (("dt_ms", "dt"), ("noise_sigma", "noise_sigma")):
-        if float(net.meta[meta]) != float(config[key]):
-            out.append(f"{meta}={net.meta[meta]}, configuration states {config[key]}")
-    return out
-
-
 def _joined(parts, get):
     """One array of every partition's ``get(part)``, in row order (a view
     where there is one partition)."""
@@ -163,58 +143,27 @@ def _joined(parts, get):
     return arrays[0] if len(arrays) == 1 else np.concatenate(arrays)
 
 
-def reference_network(net, copy_weights: bool) -> reference.Network:
+def reference_network(net, model, copy_weights: bool) -> reference.Network:
     """What the reference reads out of the dCSR network: the partitions
-    joined in row order, their columns being row indices of the whole.
-    A save rewrites the weights in place, so a run that saves copies them
-    first; the topology is never rewritten."""
+    joined in row order, their columns being row indices of the whole,
+    and the per-neuron part as ``model`` (the configuration's model file)
+    reads it.  A save rewrites the weights in place, so a run that saves
+    copies them first; the topology is never rewritten."""
     parts = net.parts
     first_edge = np.cumsum([0] + [p.row_ptr[-1] for p in parts])
     row_ptr = np.concatenate(
         [[0]] + [p.row_ptr[1:] + e for p, e in zip(parts, first_edge)]
     ).astype(np.int64)
     weight = _joined(parts, lambda p: p.edge_state[:, 0])
+    plastic_ids = [net.registry.edge_id(name) for name in model.PLASTIC_EDGES]
     return reference.Network(
         row_ptr=row_ptr,
         col=_joined(parts, lambda p: p.col_idx),
         weight=np.array(weight) if copy_weights else weight,
         delay=_joined(parts, lambda p: p.edge_state[:, 1]),
-        plastic=_joined(parts, lambda p: p.edge_model)
-        == net.registry.edge_id("syn_stdp"),
-        v0=np.array(_joined(parts, lambda p: p.vtx_state[:, 0])),
-        refrac0=np.array(_joined(parts, lambda p: p.vtx_state[:, 1])),
-        bias=np.array(_joined(parts, lambda p: p.vtx_state[:, 2])),
+        plastic=np.isin(_joined(parts, lambda p: p.edge_model), plastic_ids),
         noise_ids=np.array(_joined(parts, lambda p: p.global_ids)),
-    )
-
-
-def _noise_fn(seed: int, n: int, sigma: float, ids: np.ndarray):
-    """Step noise of the model: N(0, sigma^2) drawn by JAX's threefry from
-    the key ``fold_in(PRNGKey(seed), t)`` over the permanent neuron ids."""
-    import jax
-    import jax.numpy as jnp
-
-    key = jax.random.PRNGKey(seed)
-    draw = jax.jit(
-        lambda t: jax.random.normal(jax.random.fold_in(key, t), (n,), jnp.float32)
-    )
-    return lambda t: sigma * np.asarray(draw(t), np.float64)[ids]
-
-
-def _program_state(state: dict) -> Dict[str, np.ndarray]:
-    """The carry by row of the whole network.  A k-chip carry has a
-    leading partition axis: ``(k, n_p, ...)`` and rings ``(k, D, n_p)``."""
-    vtx = np.asarray(state["vtx_state"])
-    ring, hist = np.asarray(state["ring"], np.float64), np.asarray(state["hist"])
-    tr_plus = np.asarray(state["tr_plus"], np.float64)
-    tr_minus = np.asarray(state["tr_minus"], np.float64)
-    if vtx.ndim == 3:
-        vtx = vtx.reshape(-1, vtx.shape[-1])
-        ring, hist = (np.concatenate(list(x), axis=-1) for x in (ring, hist))
-        tr_plus, tr_minus = tr_plus.reshape(-1), tr_minus.reshape(-1)
-    return dict(
-        v=vtx[:, 0].astype(np.float64), refrac=vtx[:, 1].astype(np.float64),
-        ring=ring, hist=hist, tr_plus=tr_plus, tr_minus=tr_minus,
+        neurons=model.neurons(_joined(parts, lambda p: p.vtx_state)),
     )
 
 
@@ -235,30 +184,30 @@ def _by_id(ses, n: int):
     return np.argsort(ses.permanent_ids, kind="stable")[:n]
 
 
-def _edges_by_id(net) -> np.ndarray:
+def _edges_by_id(net, model) -> np.ndarray:
     """Every synapse as (target id, source id, delay, weight), sorted: the
     network's edge state whatever its partitioning."""
     ids = _joined(net.parts, lambda p: p.global_ids)
-    ref = reference_network(net, copy_weights=False)
+    ref = reference_network(net, model, copy_weights=False)
     tgt = np.repeat(ids, np.diff(ref.row_ptr))
     rows = np.stack([tgt, ids[ref.col], ref.delay.astype(np.int64),
                      ref.weight.view(np.int32).astype(np.int64)])
     return rows[:, np.lexsort(rows[::-1])]
 
 
-def _diff_by_id(ses, ses2, ra, rb, n: int, tmp: str) -> int:
+def _diff_by_id(ses, ses2, ra, rb, n: int, tmp: str, model) -> int:
     """``_exact_diff`` for two sessions of different partition counts: the
-    rasters and per-neuron carries by permanent id, and the weights as
-    each session's save writes them back into its dCSR."""
+    rasters and per-neuron carries by permanent id (``model.program_state``),
+    and the weights as each session's save writes them back into its dCSR."""
     sa, sb = _by_id(ses, n), _by_id(ses2, n)
     diff = int(np.count_nonzero(ra[:, sa] != rb[:, sb]))
-    pa, pb = _program_state(ses.state), _program_state(ses2.state)
+    pa, pb = model.program_state(ses.state), model.program_state(ses2.state)
     for key in pa:
         diff += int(np.count_nonzero(pa[key][..., sa] != pb[key][..., sb]))
     diff += int(ses.t != ses2.t)
     for i, s in enumerate((ses, ses2)):
         s.save(os.path.join(tmp, f"continued{i}"), wait=True)
-    ea, eb = _edges_by_id(ses.net), _edges_by_id(ses2.net)
+    ea, eb = _edges_by_id(ses.net, model), _edges_by_id(ses2.net, model)
     if ea.shape != eb.shape:
         return diff + max(ea.shape[1], eb.shape[1])
     return diff + int(np.count_nonzero((ea != eb).any(axis=0)))
@@ -318,7 +267,7 @@ def _run(args, cell, dev, devices, peak, compiles, tmp, t_start,
     import jax
     from repro.snn import Session, SimConfig
 
-    cfg, traffic, seed = cell.config, cell.traffic, args.seed
+    cfg, traffic, seed, model = cell.config, cell.traffic, args.seed, cell.model
 
     chunk = int(traffic["chunk_steps"])
     ck_every = traffic.get("checkpoint_every")
@@ -342,10 +291,13 @@ def _run(args, cell, dev, devices, peak, compiles, tmp, t_start,
         f"{r.build_s:.3f} s; engine={desc['engine']} "
         f"step_engine={desc['step_engine']} backend={desc['backend']} "
         f"ell_fill={desc.get('ell_fill')}; host peak RSS {host_rss_gb():.2f} GB")
-    departures = _departures(ses.net, cfg)
-    plastic = cfg.get("stdp") is not None
-    saves = plastic or traffic.get("restore") or ck_every is not None
-    net0 = reference_network(ses.net, copy_weights=saves)
+    departures = model.departures(ses.net, cfg)
+    saves = traffic.get("restore") or ck_every is not None
+    net0 = reference_network(ses.net, model, copy_weights=saves)
+    plastic = bool(net0.plastic.any())
+    if plastic and not saves:
+        # the epilogue saves a plastic network's weights back in place
+        net0.weight = np.array(net0.weight)
     by_id = _by_id(ses, int(cfg["n"]))
     r.panel_shapes = [tuple(w.shape) for w in ses.state["weights"]]
     log(f"[setup] ELL panels {r.panel_shapes}")
@@ -403,7 +355,7 @@ def _run(args, cell, dev, devices, peak, compiles, tmp, t_start,
         f"loaded: {setup_compiles} in set-up ({setup_hits} from the cache, "
         f"{setup_compiles - setup_hits} compiled), {window_compiles} in the "
         f"window")
-    end_state = _program_state(ses.state)
+    end_state = model.program_state(ses.state)
     raster = rec.raster()
     t_end = warm_steps + r.steps
     # the step counter of the carry has to have advanced by every step run
@@ -432,7 +384,7 @@ def _run(args, cell, dev, devices, peak, compiles, tmp, t_start,
             diff = _exact_diff(ses.state, ses2.state, live.raster(), again.raster())
         else:
             diff = _diff_by_id(ses, ses2, live.raster(), again.raster(),
-                               int(cfg["n"]), tmp)
+                               int(cfg["n"]), tmp, model)
         checks["restore_diff"] = (float(diff), cell.limits["restore_diff"])
         log(f"[restore] restored at k={restore_k} in {r.restore_s:.6f} s; one "
             f"chunk continued in both sessions: {diff} entries differ")
@@ -477,7 +429,7 @@ def _run(args, cell, dev, devices, peak, compiles, tmp, t_start,
 
     # -- the built network against the configuration's own numbers -------
     t = time.perf_counter()
-    built, lines = structure.check(net0, cfg)
+    built, lines = structure.check(net0, cfg, model)
     for line in lines:
         log(f"[structure] {line}")
     for name, value in built.items():
@@ -486,18 +438,17 @@ def _run(args, cell, dev, devices, peak, compiles, tmp, t_start,
 
     # -- the comparison with the plain reference -------------------------
     t = time.perf_counter()
-    params = reference.Params.from_config(cfg)
-    noise = _noise_fn(seed, net0.n, params.noise_sigma, net0.noise_ids)
-    replayed = reference.replay(net0, params, raster[:t_end], noise, control)
+    step_input = model.step_input(seed, net0, cfg)
+    replayed = model.replay(net0, cfg, raster[:t_end], step_input, control)
     replay_s = time.perf_counter() - t
     ref_state = replayed.state
     got = dict(end_state)
     if plastic:
         got["w"] = w_end
-    numbers = reference.gaps(got, ref_state, replayed.thr_gap, net0, by_id)
+    numbers = model.gaps(got, ref_state, replayed.thr_gap, net0, by_id)
     if control:
-        c_numbers = reference.gaps(replayed.control.state, ref_state,
-                                   replayed.control.thr_gap, net0, by_id)
+        c_numbers = model.gaps(replayed.control.state, ref_state,
+                               replayed.control.thr_gap, net0, by_id)
         c_failed = [k for k, v in c_numbers.items()
                     if k in cell.limits and not v <= cell.limits[k]]
         print(json.dumps(dict(control=control, seed=seed, program=numbers,

@@ -4,7 +4,10 @@ Everything that belongs to one configuration, one traffic mix, one cell's
 comparison limits or one metric sits in a file of its own, found by name:
 
     bench/configs/<config>.json     sizes, model parameters, precision, the
-                                    connectivity, weights and delays stated
+                                    connectivity, weights and delays stated,
+                                    and ``"reference": "<model>"``
+    bench/models/<model>.py         the neuron and synapse model the check
+                                    replays (``bench/models/README.md``)
     bench/traffic/<traffic>.json    chunking, monitors, checkpoints, restore
                                     (and its ``restore_k``)
     bench/limits/<workload>.json    the limit of each number compared
@@ -19,6 +22,8 @@ import dataclasses
 import importlib.util
 import json
 import os
+import sys
+import types
 from typing import Callable, Dict, List, Optional
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
@@ -39,6 +44,7 @@ class Cell:
     limits: Dict[str, float]
     end_to_end: List[Metric]
     per_layer: List[Metric]
+    model: types.ModuleType
 
     @property
     def name(self) -> str:
@@ -54,15 +60,33 @@ def _load_json(path: str) -> dict:
         return json.load(f)
 
 
-def load_reader(bench_dir: str, name: str) -> Callable:
-    """``read`` of ``<bench_dir>/metrics/<name>.py``."""
-    path = os.path.join(bench_dir, "metrics", f"{name}.py")
+def _load_module(bench_dir: str, kind: str, name: str) -> types.ModuleType:
+    """The module ``<bench_dir>/<kind>/<name>.py``."""
+    path = os.path.join(bench_dir, kind, f"{name}.py")
     mod_spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path
+        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path
     )
     mod = importlib.util.module_from_spec(mod_spec)
+    # a dataclass in the module looks its module up by name
+    sys.modules[mod_spec.name] = mod
     mod_spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(bench_dir: str, name: str) -> Callable:
+    """``read`` of ``<bench_dir>/metrics/<name>.py``."""
+    return _load_module(bench_dir, "metrics", name).read
+
+
+def load_model(bench_dir: str, config: dict) -> types.ModuleType:
+    """The model file the configuration names: ``<bench_dir>/models/
+    <config["reference"]>.py``."""
+    if "reference" not in config:
+        raise KeyError(
+            f"configuration {config.get('name')!r} has no \"reference\" key: "
+            "it names its model, a file bench/models/<name>.py"
+        )
+    return _load_module(bench_dir, "models", config["reference"])
 
 
 def _applies(entry: dict, workload: str, reported: set) -> bool:
@@ -76,8 +100,8 @@ def _applies(entry: dict, workload: str, reported: set) -> bool:
 
 
 def load_cell(workload: str, root: Optional[str] = None) -> Cell:
-    """The cell named ``workload`` with its configuration, traffic, limits
-    and metric readers.  ``root`` holds ``BENCHMARK.json`` and ``bench/``;
+    """The cell named ``workload`` with its configuration, model, traffic,
+    limits and metric readers.  ``root`` holds ``BENCHMARK.json`` and ``bench/``;
     a missing file raises ``FileNotFoundError``."""
     root = root or os.path.dirname(BENCH_DIR)
     bench_dir = os.path.join(root, "bench")
@@ -89,6 +113,7 @@ def load_cell(workload: str, root: Optional[str] = None) -> Cell:
         )
     w = cells[workload]
     config = _load_json(os.path.join(bench_dir, "configs", f"{w['config']}.json"))
+    model = load_model(bench_dir, config)
     traffic = _load_json(os.path.join(bench_dir, "traffic", f"{w['traffic']}.json"))
     limits = _load_json(os.path.join(bench_dir, "limits", f"{workload}.json"))
     e2e = [
@@ -102,4 +127,4 @@ def load_cell(workload: str, root: Optional[str] = None) -> Cell:
         for e in bm["per_layer"]
         if _applies(e, workload, reported)
     ]
-    return Cell(w, config, traffic, limits, e2e, per_layer)
+    return Cell(w, config, traffic, limits, e2e, per_layer, model)

@@ -17,9 +17,9 @@ the built arrays to the configuration's own numbers instead:
   and uniform over it where ``lo < hi``;
 * plasticity: a synapse is plastic exactly on the ``plastic_pairs``
   ``[source, target]``;
-* neurons: the bias mean and spread per population (``bias``), the
-  initial membrane potential uniform over ``v_init_uniform`` and no
-  neuron refractory at the start.
+* neurons: one row for each of the ``n`` permanent ids; what a neuron
+  holds at the start is the model's to check (``neuron_structure`` of the
+  configuration's model file), and its counts and z values join these.
 
 Two numbers come out: ``structure_diff``, the count of synapses and
 neurons that break an exact rule, and ``structure_z``, the widest
@@ -77,15 +77,16 @@ class _Plan:
         return np.searchsorted(self.bounds, ids, side="right")
 
 
-def _z(x, expected, se) -> float:
+def z_score(x, expected, se) -> float:
     return float(abs(x - expected) / se) if se > 0 else (0.0 if x == expected else np.inf)
 
 
-def check(net, cfg: dict) -> Tuple[Dict[str, float], List[str]]:
+def check(net, cfg: dict, model) -> Tuple[Dict[str, float], List[str]]:
     """``({"structure_diff": .., "structure_z": ..}, lines)`` for the
     reference's view of the built network (``reference.Network``, its
-    weights as built) under configuration ``cfg``; ``lines`` name each
-    part of both numbers."""
+    weights as built) under configuration ``cfg`` and its ``model`` (the
+    module of its model file); ``lines`` name each part of both
+    numbers."""
     pl = _Plan(cfg)
     P, Q = pl.P, pl.P + 1  # populations, and padding
     ids = np.asarray(net.noise_ids, np.int64)
@@ -155,7 +156,7 @@ def check(net, cfg: dict) -> Tuple[Dict[str, float], List[str]]:
                     zero_p += int(counts[t, s])
                     continue
                 pairs = float(pl.sizes[t] * pl.sizes[s])
-                z = max(z, _z(counts[t, s], p * pairs, np.sqrt(pairs * p * (1 - p))))
+                z = max(z, z_score(counts[t, s], p * pairs, np.sqrt(pairs * p * (1 - p))))
         exact["zero_p_edges"] = zero_p
         zs["pair_count"] = z
     per_src = counts[:P, :P].sum(axis=0)
@@ -168,8 +169,8 @@ def check(net, cfg: dict) -> Tuple[Dict[str, float], List[str]]:
             continue
         mean = wsum[s] / c
         spread = np.sqrt(max(w2sum[s] / c - mean * mean, 0.0))
-        zw = max(zw, _z(mean, pl.w_mean[s], sd / np.sqrt(c)),
-                 _z(spread, sd, sd / np.sqrt(2 * c)))
+        zw = max(zw, z_score(mean, pl.w_mean[s], sd / np.sqrt(c)),
+                 z_score(spread, sd, sd / np.sqrt(2 * c)))
     zs["weight"] = zw
     zd = 0.0
     for s in range(P):
@@ -177,30 +178,15 @@ def check(net, cfg: dict) -> Tuple[Dict[str, float], List[str]]:
         if lo < hi and c:
             q = 1.0 / (hi - lo + 1)
             for d in range(lo, hi + 1):
-                zd = max(zd, _z(dhist[s, d], c * q, np.sqrt(c * q * (1 - q))))
+                zd = max(zd, z_score(dhist[s, d], c * q, np.sqrt(c * q * (1 - q))))
     zs["delay_uniform"] = zd
 
     real_rows = row_pop < P
     exact["rows"] = abs(int(np.count_nonzero(real_rows)) - pl.n) + (
         0 if np.array_equal(np.sort(ids[real_rows]), np.arange(pl.n)) else 1)
-    bias_mu, bias_sd = float(cfg["bias"]["mu"]), float(cfg["bias"]["sigma"])
-    v_lo, v_hi = map(float, cfg["v_init_uniform"])
-    zb = zv = 0.0
-    v0 = np.asarray(net.v0, np.float64)
-    exact["v_init"] = int(np.count_nonzero(real_rows & ((v0 < v_lo) | (v0 >= v_hi))))
-    exact["refractory_init"] = int(np.count_nonzero(
-        real_rows & (np.asarray(net.refrac0) != 0)))
-    for i in range(P):
-        rows = row_pop == i
-        c = int(np.count_nonzero(rows))
-        if not c:
-            continue
-        b = np.asarray(net.bias, np.float64)[rows]
-        zb = max(zb, _z(b.mean(), bias_mu, bias_sd / np.sqrt(c)),
-                 _z(b.std(), bias_sd, bias_sd / np.sqrt(2 * c)))
-        zv = max(zv, _z(v0[rows].mean(), (v_lo + v_hi) / 2,
-                        (v_hi - v_lo) / np.sqrt(12 * c)))
-    zs["bias"], zs["v_init"] = zb, zv
+    neuron_exact, neuron_zs = model.neuron_structure(net, cfg, row_pop, P)
+    exact.update(neuron_exact)
+    zs.update(neuron_zs)
 
     numbers = dict(structure_diff=float(sum(exact.values())),
                    structure_z=float(max(zs.values())))

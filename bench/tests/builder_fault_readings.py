@@ -24,6 +24,7 @@ def main(argv) -> int:
     name, faults = argv[0], argv[1:] or ["none", *bench_helpers.BUILDER_FAULTS]
     with open(os.path.join(spec.BENCH_DIR, "configs", f"{name}.json")) as f:
         cfg = json.load(f)
+    model = spec.load_model(spec.BENCH_DIR, cfg)
     for fault in faults:
         t = time.perf_counter()
         rules = harness.build_spec(cfg)
@@ -32,7 +33,7 @@ def main(argv) -> int:
         net = build_network(rules, k=int(cfg.get("k", 1)))
         built = time.perf_counter() - t
         numbers, lines = structure.check(
-            harness.reference_network(net, copy_weights=False), cfg)
+            harness.reference_network(net, model, copy_weights=False), cfg, model)
         print(json.dumps(dict(config=name, fault=fault, m=int(net.m),
                               build_s=built, **numbers, parts=lines[:2])),
               flush=True)

@@ -1,7 +1,9 @@
-"""The reference's replay against a plain one written here, which loops over
-every edge of every spiking source, for both configurations' parameters
-(STDP in the Brunel one) and for the bfloat16 control; and the count of
-synaptic events the replay visits."""
+"""The reference's replay of the configurations' model (``bench/models/
+lif_delta.py`` through the shared loop of ``bench/reference.py``) against a
+plain one written here, which loops over every edge of every spiking
+source, for both configurations' parameters (STDP in the Brunel one) and
+for the bfloat16 control; and the count of synaptic events the replay
+visits."""
 import json
 import os
 
@@ -14,12 +16,19 @@ from bench import reference, spec
 CONFIGS = ("pd14_microcircuit", "brunel2000_stdp")
 
 
-def _params(name: str) -> reference.Params:
+def _config(name: str) -> dict:
     with open(os.path.join(spec.BENCH_DIR, "configs", f"{name}.json")) as f:
-        return reference.Params.from_config(json.load(f))
+        return json.load(f)
 
 
-def _network(p: reference.Params, n=240, mean_in=30, d_max=15, seed=0):
+lif_delta = spec.load_model(spec.BENCH_DIR, _config(CONFIGS[0]))
+
+
+def _params(name: str):
+    return lif_delta.Params.from_config(_config(name))
+
+
+def _network(p, n=240, mean_in=30, d_max=15, seed=0):
     """A random network with the configuration's parameters: every row's
     in-degree, sources and delays drawn, some neurons with no in-edge,
     weights of both signs, and with STDP half the synapses plastic."""
@@ -34,10 +43,12 @@ def _network(p: reference.Params, n=240, mean_in=30, d_max=15, seed=0):
         weight=(rng.normal(0.2, 0.4, m) * (p.v_thresh - p.v_rest) / 10).astype(np.float32),
         delay=rng.integers(1, d_max + 1, m).astype(np.float32),
         plastic=plastic,
-        v0=rng.uniform(p.v_reset, p.v_thresh, n),
-        refrac0=np.zeros(n),
-        bias=np.full(n, 0.3 * (p.v_thresh - p.v_rest) / p.r_m),
         noise_ids=np.arange(n),
+        neurons=dict(
+            v0=rng.uniform(p.v_reset, p.v_thresh, n),
+            refrac0=np.zeros(n),
+            bias=np.full(n, 0.3 * (p.v_thresh - p.v_rest) / p.r_m),
+        ),
     )
 
 
@@ -53,7 +64,7 @@ def _noise(p, n):
     return lambda t: np.random.default_rng(1000 + t).normal(0.0, p.noise_sigma, n)
 
 
-class _PlainReplayer(reference._Replayer):
+class _PlainReplayer(lif_delta._Replayer):
     """Propagation and STDP by a loop over every edge of every spiking
     source (and over every plastic edge), one edge at a time."""
 
@@ -64,8 +75,9 @@ class _PlainReplayer(reference._Replayer):
         for e, s in enumerate(net.col):
             self.out[s].append(e)
 
-    def advance(self, t, v_new, spikes, src=None):
+    def advance(self, t, pre, spikes, src=None):
         q, p, net = self.q, self.p, self.net
+        v_new = pre[0]
         slot = t % self.D
         self.ring[slot] = 0.0
         self.refrac = np.where(spikes, self.ref_steps,
@@ -100,7 +112,7 @@ def _plain_replay(net, p, raster, noise, q):
     step on its own membrane, with the raster's spikes."""
     rep = _PlainReplayer(net, p, q)
     for t in range(raster.shape[0]):
-        rep.advance(t, rep.membrane(t, noise(t))[0], raster[t].astype(bool))
+        rep.advance(t, rep.integrate(t, noise(t)), raster[t].astype(bool))
     return rep
 
 
@@ -120,7 +132,7 @@ def test_replay_equals_plain_per_edge_replay(config, control):
     net = _network(p)
     raster = _raster(net.n)
     noise = _noise(p, net.n)
-    got = reference.replay(net, p, raster, noise, control=control)
+    got = lif_delta.replay(net, _config(config), raster, noise, control=control)
     plain = _plain_replay(net, p, raster, noise,
                           reference._bf16 if control else reference._exact)
     ran = got.control if control else got
@@ -136,13 +148,13 @@ def test_replay_visits_exactly_the_raster_events(config):
     """Each step visits the out-edges of its spiking sources, no more:
     the replay's count is the raster's synaptic events, for the float64
     replay and for the control alike."""
-    p = _params(config)
+    p, cfg = _params(config), _config(config)
     net = _network(p, seed=3)
     raster = _raster(net.n, steps=40, seed=4)
     out_degree = np.bincount(net.col, minlength=net.n)
     events = int(raster.sum(axis=0, dtype=np.int64) @ out_degree)
-    got = reference.replay(net, p, raster, _noise(p, net.n), control=True)
+    got = lif_delta.replay(net, cfg, raster, _noise(p, net.n), control=True)
     assert got.events == got.control.events == events
     # a raster in which nobody fires visits nothing
-    quiet = reference.replay(net, p, np.zeros_like(raster[:5]), _noise(p, net.n))
+    quiet = lif_delta.replay(net, cfg, np.zeros_like(raster[:5]), _noise(p, net.n))
     assert quiet.events == 0

@@ -2,8 +2,8 @@
 skipped: the reference agrees with ``Session.run`` for both
 configurations and for save -> restore -> continue; the bfloat16 control
 and each planted fault of the step or of the network builder come out as
-not correct; a new
-configuration, traffic mix and metric are picked up from their files."""
+not correct; a new configuration, traffic mix, metric and model file are
+picked up from their files."""
 import json
 import os
 import subprocess
@@ -137,6 +137,110 @@ def test_new_files_are_picked_up(tiny, tmp_path, capsys):
     rc, res = bench_helpers.run_cell(root, "tiny_new", seconds=0.3, capsys=capsys)
     assert rc == 0 and res["correct"], res["checks"]
     assert res["metrics"]["steps_per_chunk"] == {"value": 7.0, "unit": "steps"}
+
+
+# a model file of a test's own: the configurations' LIF, its parameters
+# read from the configuration's own key "lif_model" (no top-level neuron,
+# noise, bias, initial-state or STDP key); with NO_BIAS its replay leaves
+# the bias current out
+_OWN_MODEL = """
+import dataclasses
+import os
+
+import numpy as np
+
+from bench import spec
+
+base = spec.load_model(os.path.dirname(os.path.dirname(__file__)),
+                       {{"reference": "lif_delta"}})
+NO_BIAS = {no_bias}
+
+PLASTIC_EDGES = base.PLASTIC_EDGES
+neurons, program_state, gaps = base.neurons, base.program_state, base.gaps
+
+
+def _own(cfg):
+    return dict(cfg, **cfg["lif_model"])
+
+
+def departures(net, cfg):
+    return base.departures(net, _own(cfg))
+
+
+def step_input(seed, net, cfg):
+    return base.step_input(seed, net, _own(cfg))
+
+
+def replay(net, cfg, raster, step_input, control=False):
+    if NO_BIAS:
+        net = dataclasses.replace(net, neurons=dict(
+            net.neurons, bias=np.zeros_like(net.neurons["bias"])))
+    return base.replay(net, _own(cfg), raster, step_input, control)
+
+
+def neuron_structure(net, cfg, row_pop, n_pops):
+    return base.neuron_structure(net, _own(cfg), row_pop, n_pops)
+"""
+
+_MODEL_KEYS = ("neuron", "noise_sigma", "bias", "v_init_uniform", "stdp")
+
+
+@pytest.mark.parametrize("no_bias", [False, True], ids=["sound", "replay_without_bias"])
+def test_a_model_file_is_picked_up(tmp_path, no_bias, capsys):
+    """A configuration that names a model file of its own, whose
+    parameters sit under its own key, runs without an edit to any file
+    that was there; a model file that replays the network without its
+    bias makes the same run not correct, so the harness replays the file
+    the configuration names."""
+    root = bench_helpers.tiny_root(str(tmp_path))
+    bench = os.path.join(root, "bench")
+    with open(os.path.join(bench, "models", "own_lif.py"), "w") as f:
+        f.write(_OWN_MODEL.format(no_bias=no_bias))
+    with open(os.path.join(bench, "configs", "tiny_brunel.json")) as f:
+        cfg = json.load(f)
+    cfg["lif_model"] = {k: cfg.pop(k) for k in _MODEL_KEYS}
+    cfg["reference"] = "own_lif"
+    with open(os.path.join(bench, "configs", "tiny_brunel_own.json"), "w") as f:
+        json.dump(cfg, f)
+    with open(os.path.join(bench, "limits", "tiny_own.json"), "w") as f:
+        with open(os.path.join(bench, "limits", "bal_stdp_k1.json")) as g:
+            f.write(g.read())
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bm = json.load(f)
+    bm["configs"].append(dict(name="tiny_brunel_own", source="test",
+                              file="bench/configs/tiny_brunel_own.json",
+                              reduced=[], why="test"))
+    bm["workloads"].append(dict(name="tiny_own", config="tiny_brunel_own",
+                                traffic="steady_rates_c20", chips=1, why="test"))
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bm, f)
+    rc, res = bench_helpers.run_cell(root, "tiny_own", seconds=0.3, capsys=capsys)
+    assert rc == 0
+    failed = {k for k, c in res["checks"].items() if not c["value"] <= c["limit"]}
+    if not no_bias:
+        assert res["correct"], res["checks"]
+        assert "w_gap" in res["checks"], "the plastic weights were not compared"
+    else:
+        assert not res["correct"]
+        assert "v_gap_mv" in failed
+        assert failed <= {"v_gap_mv", "thr_gap_mv", "refrac_diff", "hist_diff",
+                          "ring_gap", "w_gap", "trace_gap"}, failed
+
+
+def test_configuration_without_reference_fails_at_load(tmp_path):
+    """A configuration must name its model file: without the key the
+    cell does not load, and the message names the key."""
+    from bench import spec
+
+    root = bench_helpers.tiny_root(str(tmp_path))
+    path = os.path.join(root, "bench", "configs", "tiny_brunel.json")
+    with open(path) as f:
+        cfg = json.load(f)
+    del cfg["reference"]
+    with open(path, "w") as f:
+        json.dump(cfg, f)
+    with pytest.raises(KeyError, match='"reference"'):
+        spec.load_cell("tiny_bal", root)
 
 
 _SPLIT_CELLS = """
